@@ -43,7 +43,7 @@ cycles are timed interleaved, pair by pair, and the reported speedup is
 the MEDIAN of per-pair ratios, which cancels machine drift that would
 otherwise swamp a CI box.
 
-    PYTHONPATH=src python -m benchmarks.bench_ingest [--quick] [--compiled]
+    PYTHONPATH=src python -m benchmarks.bench_ingest [--quick]
 """
 from __future__ import annotations
 
@@ -376,8 +376,6 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    common.add_mode_flags(ap)
     args = ap.parse_args()
-    common.set_kernel_mode(args.mode)
     print("name,us_per_call,derived")
     common.emit(run(quick=args.quick))

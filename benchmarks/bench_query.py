@@ -84,13 +84,13 @@ def _fusion_rows(quick: bool):
         def fused(tb, k):
             return fused_query_pallas(tb, k, seeds=seeds, width=spec.width,
                                       counter=spec.counter,
-                                      interpret=common.interpret_flag())
+                                      interpret=not ops.on_tpu())
 
         def loop(tb, k):
             return jnp.stack([
                 query_pallas(tb[i], k[i], seeds=seeds, width=spec.width,
                              counter=spec.counter,
-                             interpret=common.interpret_flag())
+                             interpret=not ops.on_tpu())
                 for i in range(t)])
 
         t_fused, out_f = timer(fused, tables, probes)
@@ -123,7 +123,7 @@ def _window_rows(quick: bool):
             return window_query_pallas(tb, k, w, seeds=seeds,
                                        width=spec.width, counter=spec.counter,
                                        mode="sum",
-                                       interpret=common.interpret_flag())
+                                       interpret=not ops.on_tpu())
 
         @jax.jit
         def jnp_path(tb, k, w):
@@ -164,9 +164,7 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    common.add_mode_flags(ap)
     args = ap.parse_args()
-    common.set_kernel_mode(args.mode)
     print("name,us_per_call,derived")
     from benchmarks.common import emit
     emit(run(quick=args.quick))

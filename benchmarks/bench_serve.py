@@ -42,7 +42,7 @@ serve-path epoch-scheduler claims as machine-checked facts:
   * a read scopes its flush to the OWNING plane: another plane's dirty
     ring stays buffered (no cross-plane epoch on the read path).
 
-    PYTHONPATH=src python -m benchmarks.bench_serve [--quick] [--compiled]
+    PYTHONPATH=src python -m benchmarks.bench_serve [--quick]
 """
 from __future__ import annotations
 
@@ -373,8 +373,6 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    common.add_mode_flags(ap)
     args = ap.parse_args()
-    common.set_kernel_mode(args.mode)
     print("name,us_per_call,derived")
     common.emit(run(quick=args.quick))

@@ -37,7 +37,7 @@ flush epoch is still exactly ONE `update_score_rows` dispatch (packed
 storage too), a mixed epoch adds exactly one batched `tier_spill`, and a
 swap epoch adds exactly one demotion gather + one promotion scatter.
 
-    PYTHONPATH=src python -m benchmarks.bench_tiered [--quick] [--compiled]
+    PYTHONPATH=src python -m benchmarks.bench_tiered [--quick]
 """
 from __future__ import annotations
 
@@ -295,8 +295,6 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    common.add_mode_flags(ap)
     args = ap.parse_args()
-    common.set_kernel_mode(args.mode)
     print("name,us_per_call,derived")
     common.emit(run(quick=args.quick))

@@ -24,7 +24,7 @@ Three questions about the flush pipeline refactor:
      single-launch claim is machine-checked by check_regression.py, not
      prose.
 
-    PYTHONPATH=src python -m benchmarks.bench_topk [--quick] [--compiled]
+    PYTHONPATH=src python -m benchmarks.bench_topk [--quick]
 """
 from __future__ import annotations
 
@@ -443,8 +443,6 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    common.add_mode_flags(ap)
     args = ap.parse_args()
-    common.set_kernel_mode(args.mode)
     print("name,us_per_call,derived")
     common.emit(run(quick=args.quick))

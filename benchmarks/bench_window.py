@@ -32,6 +32,7 @@ from benchmarks.common import timer
 from repro.core import CMLS16, SketchSpec
 from repro.core import sketch as sk
 from repro.core.hashing import make_row_seeds
+from repro.kernels import ops
 from repro.kernels.sketch import fused_update_pallas, update_pallas
 from repro.stream import WindowSpec, window_init, window_query, window_rotate, \
     window_update
@@ -119,13 +120,13 @@ def _throughput_rows(quick: bool):
         def fused(tb, k, m, u):
             return fused_update_pallas(tb, k, m, u, seeds=seeds,
                                        width=spec.width, counter=spec.counter,
-                                       interpret=common.interpret_flag())
+                                       interpret=not ops.on_tpu())
 
         def loop(tb, k, m, u):
             return jnp.stack([
                 update_pallas(tb[i], k[i], m[i], u[i], seeds=seeds,
                               width=spec.width, counter=spec.counter,
-                              interpret=common.interpret_flag())
+                              interpret=not ops.on_tpu())
                 for i in range(t)])
 
         t_fused, out_f = timer(fused, tables, sorted_keys, mult, unif)
@@ -157,9 +158,7 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    common.add_mode_flags(ap)
     args = ap.parse_args()
-    common.set_kernel_mode(args.mode)
     print("name,us_per_call,derived")
     from benchmarks.common import emit
     emit(run(quick=args.quick))

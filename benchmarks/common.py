@@ -11,32 +11,14 @@ import numpy as np
 from repro.configs.paper_sketch import CFG as PAPER
 from repro.core import sketch as sk
 from repro.data import corpus, ngrams
-from repro.kernels import ops
-
-# "interpret" (Pallas interpreter, any backend — CI's mode) or "compiled"
-# (real pallas_call lowering — the mode for TPU hardware numbers).  Set via
-# benchmarks/run.py --interpret/--compiled; every suite records it in its
-# JSON methodology block.
-KERNEL_MODE = "interpret"
-
-
-def set_kernel_mode(mode: str) -> None:
-    global KERNEL_MODE
-    if mode not in ("interpret", "compiled"):
-        raise ValueError(f"unknown kernel mode {mode!r}")
-    KERNEL_MODE = mode
-    ops.set_interpret_override(mode == "interpret")
-
-
-def interpret_flag() -> bool:
-    """The `interpret=` value benchmarks pass to direct kernel calls."""
-    return KERNEL_MODE == "interpret"
-
 
 def mode_methodology() -> dict:
-    """Execution-mode fields every suite embeds in its methodology block."""
-    return {"kernel_mode": KERNEL_MODE, "backend": jax.default_backend(),
-            "device": jax.devices()[0].device_kind}
+    """Device fields every suite embeds in its methodology block: the
+    platform and device kind JAX reports, and how many devices it sees.
+    Off-TPU the Pallas kernels run in interpret mode (`kernels.ops`)."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices())}
 
 
 def format_methodology(spec) -> dict:
@@ -51,17 +33,6 @@ def format_methodology(spec) -> dict:
     return {"counter_bits": spec.counter.bits, "packed": spec.packed,
             "bytes_per_cell": 4.0 / spec.cells_per_lane,
             "table_bytes_streamed": 4 * spec.depth * spec.storage_width}
-
-
-def add_mode_flags(ap) -> None:
-    """--interpret / --compiled on a benchmark argparser."""
-    g = ap.add_mutually_exclusive_group()
-    g.add_argument("--interpret", dest="mode", action="store_const",
-                   const="interpret", default="interpret",
-                   help="run Pallas kernels in interpreter mode (default)")
-    g.add_argument("--compiled", dest="mode", action="store_const",
-                   const="compiled",
-                   help="lower Pallas kernels for the real backend (TPU)")
 
 
 @functools.lru_cache(maxsize=2)

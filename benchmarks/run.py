@@ -1,6 +1,6 @@
 """Benchmark harness — one module per paper table/figure.
 
-    PYTHONPATH=src python -m benchmarks.run [--quick] [--interpret|--compiled]
+    PYTHONPATH=src python -m benchmarks.run [--quick]
     PYTHONPATH=src python -m benchmarks.run --suites bench_ingest,bench_topk
 
 Prints ``name,us_per_call,derived`` CSV (required format) and mirrors the
@@ -8,10 +8,9 @@ rows into results/benchmarks.json.  --suites selects a comma-separated
 subset by module name (``bench_ingest``) or display name
 (``ingest_plane``) — what CI's bench-smoke job and local pre-commit runs
 use to target the regression-gated suites instead of paying for all of
-them.  --compiled lowers the Pallas kernels for the real backend (the
-flag that turns these scripts into TPU-hardware numbers); the default
---interpret runs them in interpreter mode, and every suite records the
-mode in its JSON methodology block.
+them.  The kernels' mode follows the platform (interpret mode off-TPU),
+and every suite records the platform, device kind and device count in
+its JSON methodology block.
 
 Every invocation is observed through `repro.obs`:
 
@@ -43,9 +42,9 @@ from benchmarks import (bench_are_counts, bench_batched_divergence,
                         bench_damped_update, bench_ingest, bench_pmi,
                         bench_query, bench_serve, bench_throughput,
                         bench_tiered, bench_topk, bench_window)
-from benchmarks.common import (add_mode_flags, emit, mode_methodology,
-                               set_kernel_mode)
+from benchmarks.common import emit, mode_methodology
 from repro import obs
+from repro.launch.cache import enable_compile_cache
 from repro.kernels import ops
 
 SUITES = [
@@ -157,9 +156,8 @@ def main() -> None:
     ap.add_argument("--suites", default=None,
                     help="comma-separated subset, by module or display "
                          "name (e.g. bench_ingest,bench_topk)")
-    add_mode_flags(ap)
     args = ap.parse_args()
-    set_kernel_mode(args.mode)
+    enable_compile_cache()
 
     registry = obs.MetricsRegistry()
     # metrics= lands every span duration in a span_duration_us{span=...}

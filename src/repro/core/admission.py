@@ -14,11 +14,10 @@ stays total: every id maps to some row.
 Two decision sources share one row-mapping policy (`rows_of`):
 
   * `admit` / `observe_and_admit` — threshold the sketch estimate
-    directly.  `observe_and_admit` routes its update/query through the
-    kernel engines (`engine="auto"`: fused Pallas wrappers on TPU, the
-    bit-identical chunk-sequential XLA engine `ops.update_xla` elsewhere
-    and past the VMEM budget — the queue-append pattern) and validates
-    ids at the API boundary exactly like `CountService.enqueue`
+    directly.  `observe_and_admit` counts through the chunk-sequential
+    XLA engine `ops.update_xla` by default (bit-identical to the fused
+    Pallas wrappers, which `engine="kernel"` selects) and validates ids
+    at the API boundary exactly like `CountService.enqueue`
     (floats/negatives/>32-bit raise).
   * `admit_tracked` — decide from a heavy-hitter tracker heap instead of
     re-querying the sketch: an id is admitted iff it is a tracked
@@ -125,21 +124,17 @@ def observe_and_admit(sketch: sk.Sketch, ids: jnp.ndarray, rng: jax.Array,
     the update sweep); "xla" the jitted chunk-sequential reference
     (`ops.update_xla` — NOT the one-shot `sk.update_batched`, whose
     min-reads diverge from the kernel grid on cross-chunk cell
-    collisions); "auto" picks the kernel on TPU and the XLA engine
-    elsewhere (the queue-append pattern — the two engines are
-    bit-identical, so the choice is purely a dispatch-cost call).
+    collisions); "auto" takes the XLA engine on every platform (no
+    Pallas kernel lowers for TPU yet, and off-TPU the interpreter would
+    only add cost — the two engines are bit-identical, so the choice is
+    purely a dispatch-cost call).
     """
     if engine not in ("auto", "kernel", "xla"):
         raise ValueError(f"unknown admission engine {engine!r}")
     from repro.kernels import ops  # lazy: keep core import-light
     ids = _validated(ids)
     if engine == "auto":
-        # past the VMEM budget ops.update would fall back to the ONE-SHOT
-        # jnp update, which diverges from the chunk-sequential grid on
-        # cross-chunk cell collisions — take the chunk-sequential XLA
-        # engine instead so backends stay bit-identical at every size
-        on_tpu = jax.default_backend() == "tpu"
-        engine = "kernel" if on_tpu and ops.fits_vmem(sketch.spec) else "xla"
+        engine = "xla"
     elif engine == "kernel" and not ops.fits_vmem(sketch.spec):
         # an explicit kernel request past VMEM raises (as in
         # ops.update_score_rows) instead of silently downgrading

@@ -25,6 +25,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _DTYPES = {8: jnp.uint8, 16: jnp.uint16, 32: jnp.uint32}
 
@@ -78,7 +79,12 @@ class CounterSpec:
         if self.kind == "linear":
             return s
         logb = jnp.float32(math.log(self.base))
-        return jnp.expm1(s * logb) / jnp.float32(self.base - 1.0)
+        # multiply by the float32 reciprocal rather than divide: XLA
+        # rewrites division by a constant into exactly this inside a jit,
+        # so eager and fused decodes (the read path vs the flush epoch's
+        # re-score) agree bit for bit
+        inv = np.float32(1.0) / np.float32(self.base - 1.0)
+        return jnp.expm1(s * logb) * inv
 
     def point_mass(self, state: jnp.ndarray) -> jnp.ndarray:
         """Value(c+1) - Value(c) = b^c: estimate mass of one state step."""
@@ -103,9 +109,13 @@ class CounterSpec:
             return jnp.floor(v)
         logb = jnp.float32(math.log(self.base))
         c = jnp.floor(jnp.log1p(v * jnp.float32(self.base - 1.0)) / logb)
-        # guard float roundoff: never let Value(c) exceed v by a full step
+        # guard float roundoff both ways: never let Value(c) exceed v by a
+        # full step, and never stop one state short when Value(c + 1) <= v
+        # (log1p rounds some exact decodes, e.g. of CMLS8 state 246, down)
         too_high = self.decode(c) > v + 1e-6 * jnp.maximum(v, 1.0)
-        return jnp.maximum(c - too_high.astype(jnp.float32), 0.0)
+        c = c - too_high.astype(jnp.float32)
+        too_low = self.decode(c + 1.0) <= v
+        return jnp.maximum(c + too_low.astype(jnp.float32), 0.0)
 
     def reencode_stochastic(self, value: jnp.ndarray,
                             rng: "jax.Array | None" = None) -> jnp.ndarray:

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 from repro.core import admission
 from repro.core import sketch as sk
 from repro.core import topk
@@ -173,7 +171,7 @@ def _dispatch_layout(keys: jnp.ndarray, n_shards: int, capacity: int):
 def routed_update(local: sk.Sketch, keys: jnp.ndarray, rng: jax.Array,
                   axis_name: str, capacity: int) -> sk.Sketch:
     """Update a key-routed sketch (call inside shard_map over `axis_name`)."""
-    n_shards = compat.axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     buf, _, _ = _dispatch_layout(keys, n_shards, capacity)
     # (n_shards, cap) -> received (n_shards, cap): row j came from device j
     recv = jax.lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0)
@@ -209,7 +207,7 @@ def routed_query(local: sk.Sketch, keys: jnp.ndarray, axis_name: str,
     Keys dropped by capacity overflow return -1.0 (caller may retry or fall
     back to a replicated sketch; overflow is sized away in practice).
     """
-    n_shards = compat.axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     buf, slot_of_key, kept = _dispatch_layout(keys, n_shards, capacity)
     recv = jax.lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0)
     flat = recv.reshape(-1)
@@ -251,7 +249,7 @@ def routed_window_update(win, keys: jnp.ndarray, rng: jax.Array,
                              "epoch=...)")
         steps = jnp.maximum(jnp.asarray(epoch, jnp.int32) - win.epoch, 0)
         win = w.window_advance_steps(win, steps)
-    n_shards = compat.axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     buf, _, _ = _dispatch_layout(keys, n_shards, capacity)
     recv = jax.lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0)
     flat = recv.reshape(-1)
@@ -341,18 +339,19 @@ def routed_window_query(win, keys: jnp.ndarray, axis_name: str,
                         engine: str = "auto") -> jnp.ndarray:
     """Query a key-routed bucket ring; estimates aligned with `keys`.
 
-    Each shard answers its partition's keys with ONE fused window-query
-    launch (in-kernel bucket reduction + lazy gamma^age decay weights, the
-    same engine as the single-chip path), then routes the estimates back.
-    Keys dropped by capacity overflow return -1.0, as in `routed_query`.
+    Each shard answers its partition's keys with ONE window-query
+    dispatch (bucket reduction + lazy gamma^age decay weights, the same
+    engine selection as the single-chip `window_query`), then routes the
+    estimates back.  Keys dropped by capacity overflow return -1.0, as in
+    `routed_query`.
 
-    shard_map has no replication rule for pallas_call, so the default
-    (fused-kernel) engine requires the enclosing shard_map to pass
-    `check_vma=False`; pass engine="jnp" to stay on the vmapped reference
-    under a replication-checked shard_map.
+    shard_map has no replication rule for pallas_call, so where "auto"
+    takes the fused kernel (off-TPU, tables within VMEM) the enclosing
+    shard_map must pass `check_vma=False`; engine="jnp" stays on the
+    jitted reference under a replication-checked shard_map.
     """
     import repro.stream.window as w
-    n_shards = compat.axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     buf, slot_of_key, kept = _dispatch_layout(keys, n_shards, capacity)
     recv = jax.lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0)
     flat = recv.reshape(-1)

@@ -20,11 +20,21 @@ The flush itself is a SINGLE-LAUNCH EPOCH: `update_score_rows` fuses the
 active-row conservative update with the heavy-hitter candidate re-query
 (the table block is scored while still VMEM-resident), and
 `window_query_stacked` refreshes every flushed window tenant's tracker
-with one multi-ring launch.  Both follow the queue-append engine pattern
-("auto" = Pallas kernel on TPU, bit-identical jitted XLA reference from
-`kernels/ref.py` elsewhere), and every wrapper here tallies its dispatches
+with one multi-ring launch.  Every wrapper here tallies its dispatches
 into the active `audit_scope()` tallies (plus the default
 `launch_counts()` scope) so launch-count claims are auditable.
+
+Engine selection follows the platform and the table size, never a user
+flag.  On TPU every "auto" choice takes the jitted XLA/jnp engine: none
+of the Pallas kernels lowers for v5e yet (rank-1 VMEM gathers, (1, capw)
+queue blocks, vector loads from SMEM), so a kernel returns to "auto"
+only once it compiles there and measures faster.  Off-TPU the kernels
+run in interpret mode and "auto" keeps the selection the parity tests
+are written against: the kernel when the table fits VMEM, the jnp
+fallback past it, and the XLA reference for the flush epoch and the
+queue append (interpreter-mode Pallas would tax those hot paths with
+per-block emulation cost).  An explicit `engine="kernel"` always runs
+the kernel, and on TPU surfaces whatever the compiler raises.
 """
 from __future__ import annotations
 
@@ -51,31 +61,22 @@ from repro.kernels.sketch import (CHUNK, LANES, _shift_to_fill,
 # VMEM budget the resident-table strategy is valid for (per TPU core).
 VMEM_TABLE_LIMIT = 12 * 1024 * 1024
 
-# None = auto (interpret off-TPU); benchmarks/run.py's --interpret/--compiled
-# flag pins it so the same scripts produce real-TPU numbers on hardware.
-_INTERPRET_OVERRIDE: bool | None = None
-
 # Per-op dispatch tally: every public wrapper below bumps its name once
 # per successful call — AFTER argument validation, whichever engine
 # (kernel, XLA reference, or past-VMEM jnp fallback) ends up serving the
 # dispatch — so callers (the service, the benchmarks) can AUDIT dispatch
 # counts: "the flush epoch is one launch" is a measured number in
-# results/bench_topk.json, not prose.
+# results/bench_topk.json, not prose.  Each dispatch is also tallied under
+# the (op, engine) that served it, so a run can show which engine the
+# platform and table size selected.
 #
 # Tallies are CONTEXT-SCOPED: `audit_scope()` pushes a fresh Counter that
 # sees exactly the dispatches issued while it is active (scopes nest —
 # every active scope is bumped), so two benchmark suites in one process
 # audit independent windows instead of sharing one module global whose
-# reset races between them.  Index 0 is the process-default scope;
+# reset races between them.  `_SCOPES[0]` is the process-default scope;
 # `launch_counts()` / `reset_launch_counts()` are thin views over it for
 # callers that predate scoping.
-_DEFAULT_SCOPE: collections.Counter = collections.Counter()
-_SCOPES: list[collections.Counter] = [_DEFAULT_SCOPE]
-
-
-def _launch(name: str) -> None:
-    for scope in _SCOPES:
-        scope[name] += 1
 
 
 class audit_scope:
@@ -87,40 +88,46 @@ class audit_scope:
 
     The yielded Counter keeps its final counts after exit (read it any
     time); concurrent/nested scopes each see every dispatch issued while
-    they were active and nothing from outside their window.
+    they were active and nothing from outside their window.  `engines`
+    counts the same dispatches keyed by (op, engine), engine one of
+    "kernel", "xla" or "jnp".
     """
 
     def __init__(self):
         self.tally = collections.Counter()
+        self.engines = collections.Counter()
 
     def __enter__(self) -> collections.Counter:
-        _SCOPES.append(self.tally)
+        _SCOPES.append(self)
         return self.tally
 
     def __exit__(self, *exc) -> None:
-        # remove by IDENTITY: Counters compare by value, so list.remove
-        # would happily detach the default scope (or a sibling) whenever
-        # its contents happen to equal this scope's tally
-        for i in range(len(_SCOPES) - 1, -1, -1):
-            if _SCOPES[i] is self.tally:
-                del _SCOPES[i]
-                break
+        _SCOPES.remove(self)  # scopes compare by identity
+
+
+_SCOPES: list[audit_scope] = [audit_scope()]
+
+
+def _launch(name: str, engine: str) -> None:
+    for scope in _SCOPES:
+        scope.tally[name] += 1
+        scope.engines[name, engine] += 1
 
 
 def launch_counts() -> dict[str, int]:
     """Snapshot of the DEFAULT scope's {op: dispatches} since its last
     reset (prefer `audit_scope()` for isolated windows)."""
-    return dict(_DEFAULT_SCOPE)
+    return dict(_SCOPES[0].tally)
 
 
 def reset_launch_counts() -> None:
-    _DEFAULT_SCOPE.clear()
+    _SCOPES[0].tally.clear()
+    _SCOPES[0].engines.clear()
 
 
-def set_interpret_override(value: bool | None) -> None:
-    """Force (True/False) or restore auto (None) kernel interpret mode."""
-    global _INTERPRET_OVERRIDE
-    _INTERPRET_OVERRIDE = value
+def on_tpu() -> bool:
+    """Whether the default backend is a TPU (the engine-selection probe)."""
+    return jax.default_backend() == "tpu"
 
 
 def fits_vmem(spec: sk.SketchSpec) -> bool:
@@ -136,16 +143,21 @@ def _seeds_tuple(spec: sk.SketchSpec) -> tuple:
 
 
 def _interpret() -> bool:
-    if _INTERPRET_OVERRIDE is not None:
-        return _INTERPRET_OVERRIDE
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
+
+
+def _kernel_auto(spec: sk.SketchSpec) -> bool:
+    """Whether "auto" takes a Pallas kernel: off-TPU only, and only for a
+    table that fits the VMEM-resident block."""
+    return fits_vmem(spec) and not on_tpu()
 
 
 def query(sketch: sk.Sketch, keys: jnp.ndarray) -> jnp.ndarray:
-    """Kernel-path sketch query; falls back to the jnp path past VMEM."""
-    _launch("query")
-    if not fits_vmem(sketch.spec):
+    """Kernel-path sketch query; the jnp path past VMEM and on TPU."""
+    if not _kernel_auto(sketch.spec):
+        _launch("query", "jnp")
         return sk.query(sketch, keys)
+    _launch("query", "kernel")
     return query_pallas(sketch.table, keys, seeds=_seeds_tuple(sketch.spec),
                         width=sketch.spec.width, counter=sketch.spec.counter,
                         interpret=_interpret(),
@@ -159,7 +171,7 @@ def query_many(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray
     1D keys are broadcast to every tenant (the common serving probe).  All
     T queries land in ONE kernel launch (the per-tenant table is the
     VMEM-resident grid block), bit-consistent with a per-tenant `query`
-    loop.  Falls back to the vmapped jnp query past the VMEM budget.
+    loop.  The vmapped jnp query serves past the VMEM budget and on TPU.
     Returns float32 (T, N).
     """
     if keys.ndim == 1:
@@ -169,9 +181,10 @@ def query_many(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray
         # output tiles unwritten — fail loudly instead
         raise ValueError(f"per-tenant keys need {tables.shape[0]} rows, "
                          f"got {keys.shape[0]}")
-    _launch("query_many")
-    if not fits_vmem(spec):
+    if not _kernel_auto(spec):
+        _launch("query_many", "jnp")
         return sk.query_stacked(tables, spec, keys)
+    _launch("query_many", "kernel")
     return fused_query_pallas(tables, keys, seeds=_seeds_tuple(spec),
                               width=spec.width, counter=spec.counter,
                               interpret=_interpret(),
@@ -188,11 +201,11 @@ def window_query_tables(tables: jnp.ndarray, spec: sk.SketchSpec,
     estimate weights (0 = expired, gamma^age = lazy decay).  mode "sum"
     or "max".  engine: "kernel" forces the Pallas path, "jnp" the pure-jnp
     reference (used inside collectives), "auto" picks the kernel when the
-    bucket table fits VMEM.  The jnp engine is the stacked reference at
-    R=1 (`ref.window_query_stacked_ref`), so the per-ring fallback and
-    the stacked tracker-refresh fallback share ONE accumulation order —
-    in-order over buckets, matching the kernel grid.  Returns float32
-    (N,).
+    bucket table fits VMEM off-TPU and the jnp engine otherwise.  The jnp
+    engine is the stacked reference at R=1 (`ref.window_query_stacked_ref`),
+    so the per-ring fallback and the stacked tracker-refresh fallback
+    share ONE accumulation order — in-order over buckets, matching the
+    kernel grid.  Returns float32 (N,).
     """
     if mode not in ("sum", "max"):
         raise ValueError(f"unknown window query mode {mode!r}")
@@ -201,9 +214,9 @@ def window_query_tables(tables: jnp.ndarray, spec: sk.SketchSpec,
     if weights.shape != (tables.shape[0],):
         raise ValueError(f"need one weight per bucket: weights "
                          f"{weights.shape} vs {tables.shape[0]} buckets")
-    _launch("window_query")
     if engine == "auto":
-        engine = "kernel" if fits_vmem(spec) else "jnp"
+        engine = "kernel" if _kernel_auto(spec) else "jnp"
+    _launch("window_query", engine)
     if engine == "jnp":
         return ref.window_query_stacked_ref(
             tables[None], keys[None], weights[None], _row_seeds_array(spec),
@@ -216,10 +229,16 @@ def window_query_tables(tables: jnp.ndarray, spec: sk.SketchSpec,
 
 
 def update(sketch: sk.Sketch, keys: jnp.ndarray, rng: jax.Array) -> sk.Sketch:
-    """Kernel-path batched conservative update (dedup + n-fold + scatter-max)."""
-    _launch("update")
+    """Kernel-path batched conservative update (dedup + n-fold + scatter-max).
+
+    Past the VMEM budget the one-shot jnp update serves; on TPU a table
+    that fits takes the chunk-sequential XLA engine (`update_xla`)."""
     if not fits_vmem(sketch.spec):
+        _launch("update", "jnp")
         return sk.update_batched(sketch, keys, rng)
+    if on_tpu():
+        return update_xla(sketch, keys, rng)
+    _launch("update", "kernel")
     sorted_keys, mult = sk._dedup(keys)
     uniforms = jax.random.uniform(rng, sorted_keys.shape)
     table = update_pallas(sketch.table, sorted_keys, mult, uniforms,
@@ -242,13 +261,13 @@ def _update_xla_jit(table, keys, rng, *, spec):
 
 def update_xla(sketch: sk.Sketch, keys: jnp.ndarray, rng: jax.Array
                ) -> sk.Sketch:
-    """Bit-identical XLA engine of `update` (the queue-append pattern's
-    off-TPU half): same dedup and uniform draw, applied through the
+    """Bit-identical XLA engine of `update` (what it runs on TPU): same
+    dedup and uniform draw, applied through the
     CHUNK-sequential reference so a key in chunk 2 sees chunk 1's writes
     exactly as the kernel grid does — `sk.update_batched`'s one-shot
     min-read would diverge on cross-chunk cell collisions.
     """
-    _launch("update")
+    _launch("update", "xla")
     table = _update_xla_jit(sketch.table, keys, rng, spec=sketch.spec)
     return sk.Sketch(table=table, spec=sketch.spec)
 
@@ -263,6 +282,39 @@ def _parity_uniforms(rng, n_cols: int, total: int, rows):
     counters a dense flush would have.
     """
     return jax.random.uniform(rng, (total, n_cols))[rows]
+
+
+def _update_stack_xla(tables, keys, weights, rng, urows, *, spec, total):
+    """Chunk-sequential XLA engine of the fused update kernels over an
+    (R, d, w) stack: vmapped dedup, the parity uniforms grid at `urows`,
+    then `ref.update_chunked_ref` per row — bit-identical to the kernel
+    grid."""
+    sorted_keys, mult = jax.vmap(sk.dedup_weighted)(keys, weights)
+    uniforms = _parity_uniforms(rng, keys.shape[1], total, urows)
+    seeds = _row_seeds_array(spec)
+
+    def one(table, k, m, u):
+        return ref.update_chunked_ref(table, k, m, u, seeds, spec.counter,
+                                      CHUNK, cpl=spec.cells_per_lane)
+    return jax.vmap(one)(tables, sorted_keys, mult, uniforms)
+
+
+_update_stack_xla_jit = jax.jit(_update_stack_xla,
+                                static_argnames=("spec", "total"))
+
+
+def _update_rows_xla(tables, keys, weights, rng, rows, urows, *, spec,
+                     total):
+    new = _update_stack_xla(tables[rows], keys, weights, rng, urows,
+                            spec=spec, total=total)
+    return tables.at[rows].set(new)
+
+
+_update_rows_xla_jit = jax.jit(_update_rows_xla,
+                               static_argnames=("spec", "total"))
+_update_rows_xla_donated_jit = jax.jit(
+    _update_rows_xla, static_argnames=("spec", "total"),
+    donate_argnames=("tables",))
 
 
 # The flush hot path — weighted dedup, uniform draw, fused kernel — runs
@@ -322,7 +374,9 @@ def update_many(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray,
     kernel launch (the per-tenant table is the VMEM-resident grid block);
     dedup + uniform draw + kernel run as a single jitted computation.
     Entries with weight 0 are no-ops — ragged tenant queues pad with them.
-    Falls back to a vmapped jnp update for tables past the VMEM budget.
+    Falls back to a vmapped jnp update for tables past the VMEM budget; on
+    TPU a table that fits takes the chunk-sequential XLA engine, which
+    lands the kernel's bits.
 
     uniform_rows: optional (total, rows) pair — draw the uniforms over a
     (total, N) grid and gather `rows`, so updating an R-row sub-stack
@@ -331,8 +385,8 @@ def update_many(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray,
     """
     if weights is None:
         weights = jnp.ones(keys.shape, jnp.float32)
-    _launch("update_many")
     if not fits_vmem(spec):
+        _launch("update_many", "jnp")
         if uniform_rows is None:
             rngs = jax.random.split(rng, tables.shape[0])
         else:
@@ -343,6 +397,16 @@ def update_many(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray,
             s = sk.Sketch(table=table, spec=spec)
             return sk.update_batched(s, k, r, weights=w).table
         return jax.vmap(one)(tables, keys, weights, rngs)
+    if on_tpu():
+        _launch("update_many", "xla")
+        if uniform_rows is None:
+            total, rows = tables.shape[0], np.arange(tables.shape[0])
+        else:
+            total, rows = uniform_rows
+        return _update_stack_xla_jit(tables, keys, weights, rng,
+                                     np.asarray(rows, np.int32), spec=spec,
+                                     total=int(total))
+    _launch("update_many", "kernel")
     if uniform_rows is None:
         return _update_many_jit(tables, keys, weights, rng, spec=spec,
                                 interpret=_interpret())
@@ -367,7 +431,9 @@ def update_rows(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray,
     `update_many` fed the whole plane with the inactive rows' weights
     zeroed — the active-row flush can replace the dense flush without
     changing a single landed counter.  Falls back to a vmapped jnp update
-    + row scatter past the VMEM budget.
+    + row scatter past the VMEM budget; on TPU a table that fits takes the
+    chunk-sequential XLA engine (gather, update, scatter in one jitted
+    computation, bit-identical to the kernel).
 
     uniform_rows: optional (total, urows) pair decoupling the parity
     uniform draw from the kernel row map — the window plane updates flat
@@ -388,8 +454,8 @@ def update_rows(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray,
         urows = np.asarray(urows, np.int32)
     if weights is None:
         weights = jnp.ones(keys.shape, jnp.float32)
-    _launch("update_rows")
     if not fits_vmem(spec):
+        _launch("update_rows", "jnp")
         rngs = jax.random.split(rng, int(total))[urows]
 
         def one(table, k, w, r):
@@ -397,6 +463,12 @@ def update_rows(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray,
             return sk.update_batched(s, k, r, weights=w).table
         new = jax.vmap(one)(tables[rows], keys, weights, rngs)
         return tables.at[rows].set(new)
+    if on_tpu():
+        _launch("update_rows", "xla")
+        fn = _update_rows_xla_donated_jit if donate else _update_rows_xla_jit
+        return fn(tables, keys, weights, rng, rows, urows, spec=spec,
+                  total=int(total))
+    _launch("update_rows", "kernel")
     fn = _update_rows_donated_jit if donate else _update_rows_jit
     return fn(tables, keys, weights, rng, rows, urows, spec=spec,
               total=int(total), interpret=_interpret())
@@ -459,10 +531,8 @@ def update_score_rows(tables: jnp.ndarray, spec: sk.SketchSpec,
 
     engine: "kernel" forces the Pallas path, "xla" the jitted reference
     (`ref.update_score_rows_ref` — chunk-sequential, bit-identical), and
-    "auto" picks the kernel on TPU and the XLA reference elsewhere (the
-    queue-append pattern: interpreter-mode Pallas would tax the flush hot
-    path with per-block emulation cost).  Tables past the VMEM budget
-    always take the XLA engine.  Returns (new_tables, estimates).
+    "auto" takes the XLA reference on every platform (see the module
+    docstring).  Returns (new_tables, estimates).
     """
     if engine not in ("auto", "kernel", "xla"):
         raise ValueError(f"unknown update_score engine {engine!r}")
@@ -474,19 +544,19 @@ def update_score_rows(tables: jnp.ndarray, spec: sk.SketchSpec,
         urows = np.asarray(urows, np.int32)
     if weights is None:
         weights = jnp.ones(keys.shape, jnp.float32)
-    interpret = _interpret()
     if engine == "auto":
-        engine = "xla" if (interpret or not fits_vmem(spec)) else "kernel"
+        engine = "xla"
     if engine == "kernel" and not fits_vmem(spec):
         raise ValueError("table exceeds the VMEM budget; use engine='xla'")
-    _launch("update_score_rows")
+    _launch("update_score_rows", engine)
     if engine == "xla":
         return _update_score_rows_xla_jit(tables, keys, weights, rng, rows,
                                           urows, cand, spec=spec,
                                           total=int(total))
     return _update_score_rows_kernel_jit(tables, keys, weights, rng, rows,
                                          urows, cand, spec=spec,
-                                         total=int(total), interpret=interpret)
+                                         total=int(total),
+                                         interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "mode"))
@@ -524,9 +594,9 @@ def window_query_stacked(tables: jnp.ndarray, spec: sk.SketchSpec,
     path ever restacks rings on the host.
 
     engine: "auto" follows the per-ring `window_query_tables` policy —
-    the kernel whenever the bucket table fits VMEM, the reference
+    the kernel whenever the bucket table fits VMEM off-TPU, the reference
     (`ref.window_query_stacked_ref`, which the per-ring jnp fallback also
-    runs at R=1) past it — NOT the queue-append off-TPU-XLA choice: the
+    runs at R=1) past it and on TPU — NOT the queue-append XLA choice: the
     in-order weighted float accumulation is only bitwise reproducible
     within one engine family (mode="max" and the bucket estimates
     themselves ARE cross-engine bit-identical; the "sum" rounding is
@@ -547,10 +617,10 @@ def window_query_stacked(tables: jnp.ndarray, spec: sk.SketchSpec,
                          f"{(n_rings, tables.shape[1])}")
     interpret = _interpret()
     if engine == "auto":
-        engine = "kernel" if fits_vmem(spec) else "xla"
+        engine = "kernel" if _kernel_auto(spec) else "xla"
     if engine == "kernel" and not fits_vmem(spec):
         raise ValueError("table exceeds the VMEM budget; use engine='xla'")
-    _launch("window_query_stacked")
+    _launch("window_query_stacked", engine)
     if rows is not None:
         rows = jnp.asarray(np.asarray(rows, np.int32))
         if engine == "xla":
@@ -593,7 +663,7 @@ def window_advance_rows(tables: jnp.ndarray, cursors, steps) -> jnp.ndarray:
     steps >= B.  The caller owns the host cursor mirror:
     `cursor' = (cursor + steps) % B`.
     """
-    _launch("window_advance_rows")
+    _launch("window_advance_rows", "xla")
     return _window_advance_rows_jit(tables,
                                     jnp.asarray(np.asarray(cursors, np.int32)),
                                     jnp.asarray(np.asarray(steps, np.int32)))
@@ -653,19 +723,18 @@ def queue_append(queue: jnp.ndarray, keys: jnp.ndarray, rows, fill, count,
 
     engine: "kernel" forces the Pallas path, "xla" the jitted gather/
     merge/scatter reference (bit-identical; what tests cross-check), and
-    "auto" — like `window_query_tables` — picks the kernel on TPU and the
-    XLA reference elsewhere, where interpreter-mode Pallas would tax the
-    ingest hot path with per-block emulation cost.
+    "auto" takes the XLA reference on every platform (see the module
+    docstring).
     """
     if engine not in ("auto", "kernel", "xla"):
         raise ValueError(f"unknown queue_append engine {engine!r}")
-    _launch("queue_append")
+    if engine == "auto":
+        engine = "xla"
+    _launch("queue_append", engine)
     rows = np.asarray(rows, np.int32)
     fill = np.asarray(fill, np.int32)
     count = np.asarray(count, np.int32)
     interpret = _interpret()
-    if engine == "auto":
-        engine = "xla" if interpret else "kernel"
     aligned = not fill.any()  # append-right-after-flush: plain masked copy
     if rows.shape[0] == queue.shape[0] and \
             np.array_equal(rows, np.arange(queue.shape[0], dtype=np.int32)):
@@ -732,18 +801,6 @@ def _tier_spill_score_jit(tables, keys, weights, rng, urows, cand, *, spec,
                                      cpl=spec.cells_per_lane)
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "total"))
-def _tier_spill_jit(tables, keys, weights, rng, urows, *, spec, total):
-    sorted_keys, mult = jax.vmap(sk.dedup_weighted)(keys, weights)
-    uniforms = _parity_uniforms(rng, keys.shape[1], total, urows)
-    seeds = _row_seeds_array(spec)
-
-    def one(table, k, m, u):
-        return ref.update_chunked_ref(table, k, m, u, seeds, spec.counter,
-                                      CHUNK, cpl=spec.cells_per_lane)
-    return jax.vmap(one)(tables, sorted_keys, mult, uniforms)
-
-
 def tier_spill(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray,
                rng: jax.Array, weights: jnp.ndarray,
                uniform_rows, cand: jnp.ndarray | None = None):
@@ -761,12 +818,12 @@ def tier_spill(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray,
     just new_tables.  Tallied as "tier_spill" — never as the audited hot
     ops.
     """
-    _launch("tier_spill")
+    _launch("tier_spill", "xla")
     total, urows = uniform_rows
     urows = np.asarray(urows, np.int32)
     if cand is None:
-        return _tier_spill_jit(tables, keys, weights, rng, urows, spec=spec,
-                               total=int(total))
+        return _update_stack_xla_jit(tables, keys, weights, rng, urows,
+                                     spec=spec, total=int(total))
     return _tier_spill_score_jit(tables, keys, weights, rng, urows, cand,
                                  spec=spec, total=int(total))
 
@@ -795,7 +852,7 @@ def tier_query(tables, spec: sk.SketchSpec, keys) -> jnp.ndarray:
     if keys.shape[0] != tables.shape[0]:
         raise ValueError(f"per-tenant keys need {tables.shape[0]} rows, "
                          f"got {keys.shape[0]}")
-    _launch("tier_query")
+    _launch("tier_query", "xla")
     return _tier_query_jit(tables, keys, spec=spec)
 
 
@@ -809,7 +866,7 @@ def tier_demote(tables: jnp.ndarray, rows) -> jnp.ndarray:
     stack in ONE device computation (the caller's host copy lands them in
     the cold store).  The device ring needs NO read-back — the host queue
     mirror is authoritative for ring contents.  Tallied "tier_demote"."""
-    _launch("tier_demote")
+    _launch("tier_demote", "xla")
     return _tier_demote_jit(tables, jnp.asarray(np.asarray(rows, np.int32)))
 
 
@@ -826,7 +883,7 @@ def tier_promote(tables: jnp.ndarray, queue: jnp.ndarray, rows,
     stacks donated, aliased in place) — the single device round-trip a
     cold tenant pays to become hot.  Tallied "tier_promote"; the extended
     launch audit allows at most one per flush epoch."""
-    _launch("tier_promote")
+    _launch("tier_promote", "xla")
     rows = jnp.asarray(np.asarray(rows, np.int32))
     return _tier_promote_jit(tables, queue, rows, jnp.asarray(new_tables),
                              jnp.asarray(new_queue))

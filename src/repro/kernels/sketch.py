@@ -37,10 +37,14 @@ per-row hash/gather/scatter loop is unrolled in Python over the small depth
 d, so each row touch is a rank-1 VMEM gather/scatter.
 
 Validated in interpret=True mode on CPU against kernels/ref.py (see
-tests/test_kernels.py for the shape/dtype sweep).  `pl.pallas_call` +
-BlockSpec tiling as required for the TPU target; Mosaic caveat: the in-VMEM
-gather/scatter lowers to vector gather ops which constrain w to lane
-multiples — SketchSpec.from_memory already rounds widths to 128.
+tests/test_kernels.py for the shape/dtype sweep).  None of these kernels
+lowers for TPU v5e yet: Mosaic supports only 2D gathers, so the rank-1
+`row[cols]` gather and `.at[cols].max` scatter fail ("Only 2D gather is
+supported"), the row-indirected queue append's (1, capw) block breaks the
+(8, 128) tiling rule, and the dense append loads vectors from SMEM.  On
+TPU, `kernels.ops` therefore selects the XLA engines (`kernels/ref.py`)
+for every "auto" call; these kernels run there only when a caller asks
+for `engine="kernel"`.
 """
 from __future__ import annotations
 

@@ -33,10 +33,16 @@ the run with:
 
 `serve.prom` is Prometheus text exposition (point a scraper at it or
 diff it in CI); `serve_trace.json` loads in chrome://tracing or Perfetto.
+
+`main` returns a `ServeRun` (the service it built, the CMS32 metrics
+tenant's raw event stream for exact-count checks, per-phase wall times
+and the probe's ARE deciles) so `chip_smoke.py` can drive this path and
+check it.  A snapshot round-trip whose answers differ raises.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import tempfile
 import time
 
@@ -47,10 +53,22 @@ import jax
 from repro import obs
 from repro.core import CMLS16, CMS32, SketchSpec
 from repro.core.admission import AdmissionSpec
+from repro.launch.cache import enable_compile_cache
 from repro.stream import CountService, TierSpec, WindowPlane, WindowSpec
 
 
-def main(argv=None) -> None:
+@dataclasses.dataclass
+class ServeRun:
+    """What one `main` run built and measured."""
+    svc: CountService
+    tenants: list            # the wide plane's tenant names
+    metrics_events: np.ndarray   # every key enqueued to "metrics_qps"
+    phases: dict             # phase -> wall seconds (device work included)
+    ares: dict               # tenant -> ARE by frequency decile
+
+
+def main(argv=None) -> ServeRun:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants", type=int, default=8)
     ap.add_argument("--batches", type=int, default=50)
@@ -95,8 +113,10 @@ def main(argv=None) -> None:
     aspec = AdmissionSpec(threshold=64.0, n_fallback=1024, table_rows=1 << 16)
     svc.add_tenant("emb_ids", admission=aspec)
     rng = np.random.default_rng(args.seed)
+    phases = {}
+    metrics_events = []
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     ts = 0.0
     with jax.transfer_guard_device_to_host("disallow"):
         for _ in range(args.batches):
@@ -107,6 +127,7 @@ def main(argv=None) -> None:
                 events[name] = keys.astype(np.uint32)
             events["metrics_qps"] = (rng.zipf(1.3, 256) % 500).astype(
                 np.uint32)
+            metrics_events.append(events["metrics_qps"])
             events["emb_ids"] = (rng.zipf(1.3, args.batch) % 10_000).astype(
                 np.uint32)
             svc.enqueue_many(events)
@@ -115,7 +136,8 @@ def main(argv=None) -> None:
                         (rng.zipf(1.3, args.batch) % 10_000).astype(
                             np.uint32), ts=ts)
         svc.flush()
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
+    phases["ingest"] = dt
     total = int(svc.metrics.counter("events").value)
     flushes = int(svc.metrics.counter("flushes").value)
     print(f"[serve_counts] ingested {total} events for "
@@ -159,9 +181,10 @@ def main(argv=None) -> None:
         [np.arange(8, dtype=np.uint32) + t * 1_000_000
          for t in range(args.tenants)]
         + [np.arange(8, dtype=np.uint32)] * 4)  # metrics x2 + trending + emb
-    t0 = time.time()
-    counts = svc.query_all(probes)
-    dt_q = time.time() - t0
+    t0 = time.perf_counter()
+    counts = jax.block_until_ready(svc.query_all(probes))
+    dt_q = time.perf_counter() - t0
+    phases["query_all"] = dt_q
     for name in names[:2] + ["metrics_qps"]:
         print(f"[serve_counts] {name} hot-key counts: "
               f"{[round(float(x), 1) for x in np.asarray(counts[name])]}")
@@ -175,28 +198,35 @@ def main(argv=None) -> None:
 
     # heavy hitters straight off the tracker: refreshed by the same fused
     # launch that landed each flush, estimates exactly the query answers
+    t0 = time.perf_counter()
     hot, est = svc.topk(names[0], 5)
+    phases["topk"] = time.perf_counter() - t0
     print(f"[serve_counts] {names[0]} top-5 heavy hitters (tracker-fed): "
           f"{[(int(k), round(float(v))) for k, v in zip(hot, est)]}")
 
     # tracker-fed admission: hot ids map to private rows, cold ids share
     # the fallback space; decisions refreshed by every flush epoch
     ids = np.arange(32, dtype=np.uint32)
+    t0 = time.perf_counter()
     rows, admitted = svc.admit("emb_ids", ids)
     n_adm = int(np.asarray(admitted).sum())
+    phases["admit"] = time.perf_counter() - t0
     print(f"[serve_counts] admission plane: {n_adm}/{len(ids)} probe ids "
           f"admitted to private rows (threshold {aspec.threshold}, "
           f"{aspec.table_rows} private + {aspec.n_fallback} shared rows)")
 
     # the time-aware tenant: watermark epoch + lazy decay at query time
+    t0 = time.perf_counter()
     est_w = np.asarray(svc.query("trending", np.arange(8), n_buckets=5))
     est_d = np.asarray(svc.query("trending", np.arange(8), gamma=0.8))
+    phases["window_query"] = time.perf_counter() - t0
     print(f"[serve_counts] trending (last 5 of 8 x 60s buckets, watermark "
           f"epoch {svc.epoch_of('trending')}): "
           f"{[round(float(x)) for x in est_w]}")
     print(f"[serve_counts] trending lazy-decayed (gamma=0.8/interval):    "
           f"{[round(float(x)) for x in est_d]}")
 
+    t0 = time.perf_counter()
     with tempfile.TemporaryDirectory() as d:
         svc.snapshot(d, step=1)
         svc2 = CountService.restore(d)
@@ -208,11 +238,18 @@ def main(argv=None) -> None:
         print(f"[serve_counts] snapshot/restore roundtrip: queries match="
               f"{same}, tenants={len(svc2.tenants)}, planes="
               f"{len(svc2.planes)}, stats={svc2.stats}")
+        del svc2
+    if not same:
+        raise RuntimeError("snapshot/restore round-trip changed query "
+                           "answers")
+    phases["snapshot_roundtrip"] = time.perf_counter() - t0
 
     # accuracy SLO probe: the exact shadow slice scored by frequency decile
     # (decile 0 = coldest keys; the paper's ARE-by-decile evaluation as a
     # live metric).  record() also lands the deciles in the registry.
+    t0 = time.perf_counter()
     ares = slo_probe.record(svc)
+    phases["accuracy_probe"] = time.perf_counter() - t0
     for tenant in sorted(ares)[:3]:
         print(f"[serve_counts] {tenant} ARE by decile (cold->hot, "
               f"{len(slo_probe.counts[tenant])} shadowed keys): "
@@ -245,6 +282,12 @@ def main(argv=None) -> None:
         obs.write_chrome_trace(args.trace_out, tracer)
         print(f"[serve_counts] wrote chrome://tracing JSON -> "
               f"{args.trace_out}")
+    print("[serve_counts] phase wall times (s): "
+          + ", ".join(f"{k} {v:.3f}" for k, v in phases.items()))
+    return ServeRun(svc=svc, tenants=names,
+                    metrics_events=np.concatenate(metrics_events)
+                    if metrics_events else np.zeros(0, np.uint32),
+                    phases=phases, ares=ares)
 
 
 if __name__ == "__main__":

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.models.layers import dense
 from repro.models.params import P
 from repro.sharding import constrain

@@ -19,8 +19,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.models.layers import mlp_apply, mlp_specs
 from repro.models.params import P
 from repro.sharding import constrain
@@ -137,7 +135,7 @@ def moe_apply_a2a(params_loc, x, c: MoEConfig, *, axis_name: str = "model",
     """
     from repro.routing import local_group_by, route, send_back, ungroup
 
-    n_shards = compat.axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     e_loc = c.n_experts // n_shards
     t, d = x.shape
     logits = (x @ params_loc["router"].astype(x.dtype)).astype(jnp.float32)

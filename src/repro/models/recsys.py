@@ -21,7 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.models import attention as attn
 from repro.models.layers import (dense, embedding_bag, layer_norm)
 from repro.models.params import P

@@ -21,7 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.models import attention as attn
 from repro.models import moe as moe_lib
 from repro.models.layers import (cross_entropy, dense, embed_lookup,

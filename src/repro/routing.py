@@ -20,8 +20,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 @dataclasses.dataclass
 class Routing:
@@ -63,7 +61,7 @@ def route(payload: Any, dest: jnp.ndarray, axis_name: str, capacity: int):
     (n_shards * capacity, ...): row blocks [j*cap:(j+1)*cap] came from shard
     j; invalid rows are zero-filled (mask with routing.recv_valid).
     """
-    n_shards = compat.axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     packed, valid, slot_of_row, kept = _pack(payload, dest, n_shards, capacity)
 
     def xchg(x):

@@ -252,7 +252,7 @@ def test_resize_stacked_shrink_is_estimate_ordered():
 
 def test_routed_admit_single_shard_matches_local_policy():
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import sharded
 
     spec = SketchSpec(width=4096, depth=4, counter=CMS32)

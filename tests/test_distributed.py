@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.sharding import (GNN_RULES, LM_RULES, RECSYS_RULES, spec_for)
 
 
@@ -31,26 +32,26 @@ def _run_subprocess(body: str):
 
 
 def test_spec_for_basic_mapping():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = spec_for(("batch", None, "act_embed"), LM_RULES, mesh)
     assert spec == jax.sharding.PartitionSpec(("data",), None, None)
 
 
 def test_spec_for_drops_missing_mesh_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = spec_for(("batch",), LM_RULES, mesh)        # ("pod","data") -> data
     assert spec == jax.sharding.PartitionSpec(("data",))
 
 
 def test_spec_for_divisibility_degrades_to_replication():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     # trivially divisible by 1
     assert spec_for(("vocab",), LM_RULES, mesh, (50,)) == \
         jax.sharding.PartitionSpec(("model",))
 
 
 def test_gnn_rules_flatten_edge_parallelism():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = spec_for(("edges",), GNN_RULES, mesh, (512,))
     assert spec == jax.sharding.PartitionSpec(("data", "model"))
 
@@ -60,11 +61,12 @@ def test_key_routed_sketch_multidevice():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import SketchSpec, CMLS16, init
         from repro.core import sketch as sk, sharded
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         spec = SketchSpec(width=2048, depth=3, counter=CMLS16)
         local = init(spec)
         # replicate local sketch per shard: table (8, d, w) stacked
@@ -111,11 +113,12 @@ def test_routed_topk_multidevice():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import SketchSpec, CMS32, init
         from repro.core import sketch as sk, sharded, topk
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         spec = SketchSpec(width=8192, depth=4, counter=CMS32)
         # 16 heavy keys with distinct known counts, spread over the shards
         heavy = np.arange(100, 116, dtype=np.uint32)
@@ -160,12 +163,13 @@ def test_routed_admit_multidevice():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import SketchSpec, CMS32, init
         from repro.core import admission as adm
         from repro.core import sketch as sk, sharded, topk
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         spec = SketchSpec(width=8192, depth=4, counter=CMS32)
         heavy = np.arange(100, 116, dtype=np.uint32)
         counts = 40 + 10 * np.arange(16)     # 40..190 events per heavy key
@@ -212,12 +216,13 @@ def test_key_routed_window_multidevice():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import SketchSpec, CMLS16, sharded
         from repro.stream import WindowSpec, window_init, window_rotate
         from repro.stream import window as W
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         spec = SketchSpec(width=2048, depth=3, counter=CMLS16)
         wspec = WindowSpec(sketch=spec, buckets=4)
         win0 = window_init(wspec)
@@ -305,12 +310,13 @@ def test_key_routed_window_epoch_driven_multidevice():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import SketchSpec, CMLS16, sharded
         from repro.stream import WindowSpec, window_init
         from repro.stream import window as W
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         spec = SketchSpec(width=2048, depth=3, counter=CMLS16)
         wspec = WindowSpec(sketch=spec, buckets=4, interval=60.0)
         win0 = window_init(wspec, epoch=0)
@@ -373,11 +379,12 @@ def test_lazy_pmax_merge_multidevice():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import SketchSpec, CMS32, init
         from repro.core import sketch as sk, sharded
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         spec = SketchSpec(width=1 << 14, depth=2, counter=CMS32)
         tables = jnp.stack([init(spec).table] * 8)
         keys = jnp.asarray((np.random.default_rng(1).zipf(1.4, 8 * 512)
@@ -413,11 +420,12 @@ def test_merged_metrics_multidevice():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import sharded
         from repro import obs
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         # shard i packs [events counter, ring-fill gauge] as a value row
         vals = jnp.asarray(np.stack([[10.0 * (i + 1), float(i % 3)]
                                      for i in range(8)], 0), jnp.float32)
@@ -448,10 +456,11 @@ def test_compressed_allreduce_multidevice():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.train.compression import compressed_allreduce_mean
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 4096))
 
         def f(x):

@@ -391,7 +391,7 @@ def test_v2_checkpoint_restores_with_cold_trackers(tmp_path):
 
 def test_routed_topk_single_shard_reselects():
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import sharded
 
     spec = SketchSpec(width=4096, depth=4, counter=CMS32)

@@ -464,7 +464,7 @@ def test_routed_window_update_consumes_epoch():
     keeps this in the fast suite; the multidevice path is exercised by
     tests/test_distributed.py)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import sharded
 
     spec = WindowSpec(sketch=SketchSpec(width=512, depth=2, counter=CMLS16),

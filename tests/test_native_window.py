@@ -32,6 +32,7 @@ from repro.core import CMLS8, CMLS16, CMS32, SketchSpec
 from repro.core import sharded
 from repro.core import sketch as sk
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.stream import CountService, WindowSpec
 from repro.stream import window as w
 
@@ -250,7 +251,7 @@ def test_pmax_merge_window_stack_matches_per_ring():
     pmax is the identity on logical states, so this pins the whole-leaf
     unpack -> collective -> repack plumbing and the delegation)."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     spec = SketchSpec(width=1024, depth=2, counter=CMLS8, packed=True)
     wspec = WindowSpec(sketch=spec, buckets=3, interval=60.0)
@@ -260,7 +261,7 @@ def test_pmax_merge_window_stack_matches_per_ring():
         0, np.iinfo(np.uint32).max, (t, wspec.buckets, spec.depth,
                                      spec.storage_width),
         dtype=np.uint32))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     merged = shard_map(
         lambda x: sharded.pmax_merge_window_stack(x, spec, "data"),
         mesh=mesh, in_specs=(P(),), out_specs=P())(tables)

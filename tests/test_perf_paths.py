@@ -83,9 +83,10 @@ def test_routing_roundtrip_multidevice():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.routing import route, send_back
-        mesh = jax.make_mesh((8,), ("x",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("x",))
         def body(vals, dest):
             recv, r = route(vals[0], dest[0], "x", capacity=64)
             back = send_back(recv + 100.0, r, "x")
@@ -107,10 +108,11 @@ def test_moe_a2a_matches_dense_multidevice():
     out = _run("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.models import moe as M
         from repro.models.params import init_tree
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = M.MoEConfig(d_model=32, n_experts=8, top_k=2, d_ff_expert=16,
                           n_shared=1, norm_topk=True, capacity_factor=4.0,
                           wire_capacity_factor=4.0)
@@ -142,7 +144,8 @@ def test_dimenet_local_triplets_matches_reference():
         from repro.models import dimenet as D
         from repro.models.params import init_tree
         from repro.sharding import GNN_RULES
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         n_shards = 8
         cfg = D.DimeNetConfig(n_blocks=2, d_hidden=32, d_feat=8, n_targets=5,
                               readout="node")
@@ -190,7 +193,8 @@ def test_dlrm_a2a_lookup_matches_take():
         from repro.models.params import init_tree
         from repro.data import recsys_stream as S
         from repro.sharding import RECSYS_RULES
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = rs.DLRMConfig(embed_dim=16, bot_mlp=(13, 32, 16),
                             top_mlp=(64, 1),
                             table_sizes=tuple([20480] * 3 + [60]))

@@ -297,14 +297,15 @@ def test_window_pmax_merge_multidevice():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core import SketchSpec, CMLS16, sharded
+        from repro.launch.mesh import make_mesh
         from repro.stream import WindowSpec, window_init, window_query
         from repro.stream import window as W
 
         spec = SketchSpec(width=2048, depth=2, counter=CMLS16)
         wspec = WindowSpec(sketch=spec, buckets=4)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         win0 = window_init(wspec)
         tables = jnp.stack([win0.tables] * 8)
         keys = jnp.asarray((np.random.default_rng(0).zipf(1.4, 8 * 512)

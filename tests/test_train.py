@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as C
 from repro.train import compression as Z
 from repro.train import loop as L
@@ -89,7 +90,7 @@ def test_checkpoint_elastic_restore_resharding(tmp_path):
     root = str(tmp_path / "ck")
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     C.save(root, 1, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     target = {"w": jax.ShapeDtypeStruct(
         (4, 4), jnp.float32,
         sharding=NamedSharding(mesh, PartitionSpec("data", None)))}
