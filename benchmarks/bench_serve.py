@@ -26,10 +26,11 @@ single uniform trace proves nothing):
      with late-but-in-interval events riding every cycle and occasional
      multi-interval jumps forcing rotations mid-serve.
 
-Latency comes from the service's own tracer spans — durations recorded
-at `block_until_ready` boundaries (`Span.sync`), so p50/p99 cover the
+Latency is timed per op here: `perf_counter` around the call plus its
+own `block_until_ready` on what the op produced, so p50/p99 cover the
 device work each op claims, not just its dispatch time.  Warmup cycles
-(compilation) are excluded by clearing the tracer before the timed loop.
+(compilation) are excluded by clearing the op times before the timed
+loop.
 
 The results JSON carries a `launch_audit` section (per-op dispatch
 counts under `ops.audit_scope()`) that check_regression.py gates — the
@@ -46,28 +47,29 @@ serve-path epoch-scheduler claims as machine-checked facts:
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
 import statistics
 import time
 
+import jax
 import numpy as np
 
 from benchmarks import common
-from repro import obs
 from repro.core import CMLS16, CMS32, SketchSpec
 from repro.core.admission import AdmissionSpec
 from repro.kernels import ops
 from repro.stream import CountService, TierSpec, WindowSpec
 
 METHODOLOGY = {
-    "latency": "per-op wall time from the service's tracer spans, closed "
-               "at block_until_ready boundaries (Span.sync) — device "
+    "latency": "per-op wall time: perf_counter around the call plus its "
+               "own block_until_ready on what the op produced — device "
                "work included, async-dispatch enqueue time alone never "
                "reported.  p50/p99 are exact percentiles over the timed "
-               "cycles' span durations (warmup/compilation cycles "
-               "excluded via tracer.clear); the *_p50/*_p99 rows put "
-               "both under the calibration-normalized regression gate.",
+               "cycles' op times (warmup/compilation cycles excluded by "
+               "clearing them); the *_p50/*_p99 rows put both under the "
+               "calibration-normalized regression gate.",
     "qps": "sustained events/second over the timed serve loop, ingest "
            "AND reads included (the operator's number: what the service "
            "absorbs while also answering queries).  us_per_call = median "
@@ -106,18 +108,42 @@ METHODOLOGY = {
 PROBE_N = 64  # probes per query_all/query call in every scenario
 
 
-def _pct_rows(tracer: obs.Tracer, scenario: str, ops_wanted) -> list[dict]:
-    """p50/p99 rows per op from the tracer's recorded span durations."""
+class _OpTimes:
+    """Per-op wall times in us: `time(op, fn, ...)` runs `fn`, then blocks
+    on what it produced (or on `sync()`, for an op that returns nothing),
+    so each time covers the device work the op issued."""
+
+    def __init__(self):
+        self.durs = collections.defaultdict(list)
+
+    def time(self, op: str, fn, *args, sync=None, **kw):
+        t0 = time.perf_counter()
+        out = fn(*args, **kw)
+        jax.block_until_ready(out if sync is None else sync())
+        self.durs[op].append((time.perf_counter() - t0) * 1e6)
+        return out
+
+    def clear(self) -> None:
+        self.durs.clear()
+
+
+def _rings(svc) -> list:
+    """Every plane's device ring: what an enqueue leaves in flight."""
+    return [p.ring.queue for p in svc.planes]
+
+
+def _pct_rows(times: _OpTimes, scenario: str, ops_wanted) -> list[dict]:
+    """p50/p99 rows per op from its timed calls."""
     rows = []
     for op in ops_wanted:
-        durs = [ev["dur"] for ev in tracer.events if ev["name"] == op]
+        durs = times.durs.get(op)
         if not durs:
             continue
         p50, p99 = np.percentile(durs, 50), np.percentile(durs, 99)
         rows += [
             {"name": f"serve_{scenario}/{op}_p50",
              "us_per_call": round(float(p50)),
-             "derived": f"n={len(durs)} spans"},
+             "derived": f"n={len(durs)} calls"},
             {"name": f"serve_{scenario}/{op}_p99",
              "us_per_call": round(float(p99)),
              "derived": f"max={round(float(max(durs)))}us"},
@@ -140,9 +166,9 @@ def _qps_row(scenario: str, cycle_times, events_per_cycle: int,
 def _scenario_zipf_mix(quick: bool) -> list[dict]:
     spec = SketchSpec(width=2048, depth=2, counter=CMLS16)
     names = [f"mix{i}" for i in range(8)]
-    tracer = obs.Tracer(enabled=True)
+    times = _OpTimes()
     svc = CountService(spec, tenants=names, queue_capacity=8192, seed=0,
-                       track_top=8, tracer=tracer)
+                       track_top=8)
     svc.add_tenant("adm", admission=AdmissionSpec(
         threshold=32.0, n_fallback=512, table_rows=1 << 14))
     rng = np.random.default_rng(11)
@@ -157,22 +183,23 @@ def _scenario_zipf_mix(quick: bool) -> list[dict]:
         return ev
 
     def cycle():
-        svc.enqueue_many(events())
-        svc.query_all(probes)
-        svc.topk(names[1], 4)
-        svc.admit("adm", probes[:16])
+        times.time("enqueue_many", svc.enqueue_many, events(),
+                   sync=lambda: _rings(svc))
+        times.time("query_all", svc.query_all, probes)
+        times.time("topk", svc.topk, names[1], 4)
+        times.time("admit", svc.admit, "adm", probes[:16])
 
     warmup, reps = (1, 3) if quick else (2, 8)
     for _ in range(warmup):
         cycle()
-    tracer.clear()
+    times.clear()
     ts = []
     for _ in range(reps):
         t0 = time.perf_counter()
         cycle()
         ts.append(time.perf_counter() - t0)
     rows = [_qps_row("zipf_mix", ts, 512 * 9)]
-    rows += _pct_rows(tracer, "zipf_mix",
+    rows += _pct_rows(times, "zipf_mix",
                       ("enqueue_many", "query_all", "topk", "admit"))
     return rows
 
@@ -180,9 +207,9 @@ def _scenario_zipf_mix(quick: bool) -> list[dict]:
 def _scenario_flash_crowd(quick: bool) -> list[dict]:
     spec = SketchSpec(width=2048, depth=2, counter=CMLS16)
     names = [f"fc{i}" for i in range(8)]
-    tracer = obs.Tracer(enabled=True)
+    times = _OpTimes()
     svc = CountService(spec, tenants=names, queue_capacity=16384, seed=0,
-                       track_top=8, tracer=tracer)
+                       track_top=8)
     rng = np.random.default_rng(13)
     probes = np.arange(PROBE_N, dtype=np.uint32)
     base_n, spike_n = 256, 2560  # the 10x spike
@@ -194,15 +221,16 @@ def _scenario_flash_crowd(quick: bool) -> list[dict]:
             # the crowd converges on a handful of ids (the viral object)
             ev[names[0]] = (rng.integers(0, 32, spike_n)
                             .astype(np.uint32))
-        svc.enqueue_many(ev)
-        svc.query_all(probes)
-        svc.topk(names[0], 4)
+        times.time("enqueue_many", svc.enqueue_many, ev,
+                   sync=lambda: _rings(svc))
+        times.time("query_all", svc.query_all, probes)
+        times.time("topk", svc.topk, names[0], 4)
 
     warmup, reps = (1, 3) if quick else (2, 6)
     for _ in range(warmup):
         cycle(False)
         cycle(True)  # compile the spike shapes too: timed cycles only
-    tracer.clear()
+    times.clear()
     base_ts, spike_ts = [], []
     for _ in range(reps):
         t0 = time.perf_counter()
@@ -217,7 +245,7 @@ def _scenario_flash_crowd(quick: bool) -> list[dict]:
         _qps_row("flash_crowd", spike_ts, base_n * 7 + spike_n,
                  suffix="_spike", extra="(10x one-tenant spike)"),
     ]
-    rows += _pct_rows(tracer, "flash_crowd", ("enqueue_many", "query_all"))
+    rows += _pct_rows(times, "flash_crowd", ("enqueue_many", "query_all"))
     return rows
 
 
@@ -225,9 +253,9 @@ def _scenario_churn(quick: bool) -> list[dict]:
     spec = SketchSpec(width=1024, depth=2, counter=CMLS16)
     t, hot = 16, 4
     names = [f"ch{i:02d}" for i in range(t)]
-    tracer = obs.Tracer(enabled=True)
+    times = _OpTimes()
     svc = CountService(spec, tenants=names, queue_capacity=4096, seed=0,
-                       tracer=tracer, tier=TierSpec(max_hot_tenants=hot))
+                       tier=TierSpec(max_hot_tenants=hot))
     label = svc.planes[0].label
     rng = np.random.default_rng(17)
     probes = np.arange(PROBE_N, dtype=np.uint32)
@@ -237,13 +265,14 @@ def _scenario_churn(quick: bool) -> list[dict]:
         ev = {names[(start + i) % t]:
               (rng.zipf(1.3, 512) % 50_000).astype(np.uint32)
               for i in range(hot)}
-        svc.enqueue_many(ev)
-        svc.query_all(probes)
+        times.time("enqueue_many", svc.enqueue_many, ev,
+                   sync=lambda: _rings(svc))
+        times.time("query_all", svc.query_all, probes)
 
     warmup, reps = (2, 4) if quick else (2, 10)
     for e in range(warmup):
         cycle(e)
-    tracer.clear()
+    times.clear()
     ts = []
     for e in range(reps):
         t0 = time.perf_counter()
@@ -253,7 +282,7 @@ def _scenario_churn(quick: bool) -> list[dict]:
     demos = int(svc.metrics.counter("tier_demotions", plane=label).value)
     rows = [_qps_row("churn", ts, 512 * hot,
                      extra=f"promotions={promos} demotions={demos}")]
-    rows += _pct_rows(tracer, "churn", ("enqueue_many", "query_all"))
+    rows += _pct_rows(times, "churn", ("enqueue_many", "query_all"))
     return rows
 
 
@@ -261,9 +290,8 @@ def _scenario_watermark_skew(quick: bool) -> list[dict]:
     spec = SketchSpec(width=1024, depth=2, counter=CMLS16)
     wspec = WindowSpec(sketch=spec, buckets=8, interval=60.0)
     names = [f"wm{i}" for i in range(4)]
-    tracer = obs.Tracer(enabled=True)
-    svc = CountService(queue_capacity=8192, seed=0, track_top=8,
-                       tracer=tracer)
+    times = _OpTimes()
+    svc = CountService(queue_capacity=8192, seed=0, track_top=8)
     for n in names:
         svc.add_tenant(n, window=wspec)
     rng = np.random.default_rng(19)
@@ -283,23 +311,23 @@ def _scenario_watermark_skew(quick: bool) -> list[dict]:
             # seen time but inside the current interval (admissible
             # lateness — behind-watermark events raise instead)
             late = clocks[i] - (clocks[i] % wspec.interval) * rng.uniform()
-            svc.enqueue_many(
-                {n: (rng.zipf(1.2, 512) % 50_000).astype(np.uint32)},
-                ts=float(late))
-        svc.query_all(probes)
-        svc.topk(names[0], 4)
+            times.time("enqueue_many", svc.enqueue_many,
+                       {n: (rng.zipf(1.2, 512) % 50_000).astype(np.uint32)},
+                       ts=float(late), sync=lambda: _rings(svc))
+        times.time("query_all", svc.query_all, probes)
+        times.time("topk", svc.topk, names[0], 4)
 
     warmup, reps = (1, 3) if quick else (2, 8)
     for e in range(warmup):
         cycle(e)
-    tracer.clear()
+    times.clear()
     ts = []
     for e in range(reps):
         t0 = time.perf_counter()
         cycle(warmup + e)
         ts.append(time.perf_counter() - t0)
     rows = [_qps_row("watermark_skew", ts, 512 * 4)]
-    rows += _pct_rows(tracer, "watermark_skew",
+    rows += _pct_rows(times, "watermark_skew",
                       ("enqueue_many", "query_all", "topk"))
     return rows
 

@@ -14,9 +14,9 @@ its JSON methodology block.
 
 Every invocation is observed through `repro.obs`:
 
-  * each suite runs under `ops.audit_scope()` and a tracer span, so the
-    results JSON carries a `metrics` section — per-suite dispatch
-    tallies and wall-clock span timings — alongside the timed rows;
+  * each suite runs under `ops.audit_scope()`, so the results JSON
+    carries a `metrics` section — per-suite dispatch tallies — alongside
+    the timed rows;
   * a fixed-seed SLO probe workload (a CountService with a full-rate
     exact shadow counter) runs after the suites and scores serving
     accuracy by frequency decile; the deciles land in
@@ -25,9 +25,8 @@ Every invocation is observed through `repro.obs`:
   * a per-cell-format probe (packed cms32/log16/log8 at one constant
     byte budget, same fixed-seed stream) adds fmt_* pseudo-tenants to
     that envelope, gating the packed formats' accuracy per decile;
-  * the registry and trace export as results/metrics.prom (Prometheus
-    text exposition) and results/trace.json (chrome://tracing) — the
-    artifacts CI's bench-smoke job uploads.
+  * the registry exports as results/metrics.prom (Prometheus text
+    exposition) — an artifact CI's bench-smoke job uploads.
 """
 from __future__ import annotations
 
@@ -90,8 +89,7 @@ def _select(args) -> list:
     return [(n, f) for n, f in SUITES if _aliases(n, f) & wanted]
 
 
-def slo_probe_run(registry: obs.MetricsRegistry, tracer: obs.Tracer
-                  ) -> dict[str, list[float]]:
+def slo_probe_run(registry: obs.MetricsRegistry) -> dict[str, list[float]]:
     """Fixed-seed accuracy probe workload: a CountService fed a Zipfian
     stream with every key shadowed exactly (rate=1.0), scored by
     frequency decile.  Deterministic given SLO_SEED — both the stream and
@@ -105,8 +103,7 @@ def slo_probe_run(registry: obs.MetricsRegistry, tracer: obs.Tracer
     spec = SketchSpec(width=2048, depth=2, counter=CMLS16)
     probe = obs.AccuracyProbe(rate=1.0, capacity=8192)
     svc = CountService(spec, tenants=(SLO_TENANT,), queue_capacity=4096,
-                       seed=SLO_SEED, metrics=registry, tracer=tracer,
-                       probe=probe)
+                       seed=SLO_SEED, metrics=registry, probe=probe)
     rng = np.random.default_rng(SLO_SEED)
     for _ in range(8):
         keys = (rng.zipf(1.2, 2048) % 20_000).astype(np.uint32)
@@ -115,7 +112,7 @@ def slo_probe_run(registry: obs.MetricsRegistry, tracer: obs.Tracer
     return probe.record(svc)
 
 
-def format_probe_run(registry: obs.MetricsRegistry, tracer: obs.Tracer
+def format_probe_run(registry: obs.MetricsRegistry
                      ) -> dict[str, list[float]]:
     """Per-cell-format accuracy probe: one packed CountService per format
     (cms32 / log16 / log8) at the same FMT_BUDGET table bytes, fed the
@@ -136,8 +133,7 @@ def format_probe_run(registry: obs.MetricsRegistry, tracer: obs.Tracer
         probe = obs.AccuracyProbe(rate=1.0, capacity=8192)
         tenant = f"fmt_{fmt}"
         svc = CountService(spec, tenants=(tenant,), queue_capacity=4096,
-                           seed=SLO_SEED, metrics=registry, tracer=tracer,
-                           probe=probe)
+                           seed=SLO_SEED, metrics=registry, probe=probe)
         rng = np.random.default_rng(SLO_SEED)
         for _ in range(8):
             keys = (rng.zipf(1.2, 2048) % 20_000).astype(np.uint32)
@@ -160,16 +156,13 @@ def main() -> None:
     enable_compile_cache()
 
     registry = obs.MetricsRegistry()
-    # metrics= lands every span duration in a span_duration_us{span=...}
-    # log2 histogram, so results/metrics.prom carries p50/p99 per op
-    tracer = obs.Tracer(enabled=True, metrics=registry)
 
     print("name,us_per_call,derived")
     all_rows = []
     dispatch: dict[str, dict[str, int]] = {}
     for name, fn in _select(args):
         t0 = time.time()
-        with ops.audit_scope() as tally, tracer.span(f"suite/{name}"):
+        with ops.audit_scope() as tally:
             rows = fn(quick=args.quick)
         dispatch[name] = dict(sorted(tally.items()))
         for op, n in tally.items():
@@ -179,17 +172,16 @@ def main() -> None:
         print(f"suite/{name},{round((time.time() - t0) * 1e6)},elapsed",
               flush=True)
 
-    with ops.audit_scope() as tally, tracer.span("slo_probe"):
-        accuracy = slo_probe_run(registry, tracer)
+    with ops.audit_scope() as tally:
+        accuracy = slo_probe_run(registry)
     dispatch["slo_probe"] = dict(sorted(tally.items()))
 
-    with ops.audit_scope() as tally, tracer.span("format_probe"):
-        accuracy.update(format_probe_run(registry, tracer))
+    with ops.audit_scope() as tally:
+        accuracy.update(format_probe_run(registry))
     dispatch["format_probe"] = dict(sorted(tally.items()))
 
     metrics = {
         "dispatch": dispatch,
-        "spans": tracer.summary(),
         "accuracy_are_deciles": accuracy,
     }
     os.makedirs("results", exist_ok=True)
@@ -200,7 +192,6 @@ def main() -> None:
                                        format_probe_budget=FMT_BUDGET),
                    "are_by_decile": accuracy}, f, indent=1)
     obs.write_prometheus("results/metrics.prom", registry)
-    obs.write_chrome_trace("results/trace.json", tracer)
     for tenant, deciles in accuracy.items():
         print(f"accuracy/{tenant},,are_deciles="
               f"{'|'.join(f'{v:.4f}' for v in deciles)}")

@@ -1,18 +1,17 @@
-"""Exporters: Prometheus text exposition + chrome://tracing JSON.
+"""Exporter: Prometheus text exposition of a metrics registry.
 
-Both consume the plain-dict forms (`MetricsRegistry.snapshot()`,
-`Tracer.events`) so they serialize what a checkpoint manifest or a
-cross-process merge would see — no live objects required.
+It consumes the plain-dict form (`MetricsRegistry.snapshot()`), so it
+serializes what a checkpoint manifest or a cross-process merge would see —
+no live objects required.  Host spans need no exporter: they live in the
+`jax.profiler` trace (see `repro.obs.trace`).
 """
 from __future__ import annotations
 
-import json
 import math
 import re
 from typing import Union
 
 from repro.obs.registry import Histogram, MetricsRegistry
-from repro.obs.trace import Tracer
 
 _KEY_RE = re.compile(r"^(?P<name>[^{]+)(\{(?P<labels>.*)\})?$")
 
@@ -83,26 +82,6 @@ def to_prometheus(metrics: Union[MetricsRegistry, dict]) -> str:
     return "\n".join(lines) + "\n"
 
 
-def to_chrome_trace(trace: Union[Tracer, list]) -> dict:
-    """chrome://tracing / Perfetto 'complete event' JSON for a tracer's
-    spans (load the written file via chrome://tracing or ui.perfetto.dev
-    to see where a flush epoch spends its time)."""
-    events = trace.events if isinstance(trace, Tracer) else trace
-    return {
-        "traceEvents": [
-            {"name": ev["name"], "ph": "X", "ts": ev["ts"], "dur": ev["dur"],
-             "pid": 0, "tid": 0, "args": ev.get("args", {})}
-            for ev in events
-        ],
-        "displayTimeUnit": "ms",
-    }
-
-
 def write_prometheus(path: str, metrics: Union[MetricsRegistry, dict]) -> None:
     with open(path, "w") as f:
         f.write(to_prometheus(metrics))
-
-
-def write_chrome_trace(path: str, trace: Union[Tracer, list]) -> None:
-    with open(path, "w") as f:
-        json.dump(to_chrome_trace(trace), f, indent=1)

@@ -1,124 +1,67 @@
-"""Span tracer for the async-dispatch hot path.
+"""Host spans of the serving path, on the profiler's clock.
 
-JAX dispatch is asynchronous: a wall clock around `svc.flush()` times the
-*enqueue* of the fused launch, not the launch.  Spans therefore only
-record durations at `block_until_ready` boundaries: an enabled span
-closes by blocking on whatever arrays the caller handed to `Span.sync`
-(the flush's tables, the query's estimates), so its duration covers the
-device work it claims to cover — that is the measurement tax tracing
-opts into.
+A span is a `jax.profiler.TraceAnnotation` named `cms.<name>`: it lands in
+the same `jax.profiler` trace, on the same clock, as the device ops it
+causes, so a trace shows where the host spends the time between them.
+Keyword arguments are the span's counts (event stats in the trace);
+counts known only at close go through `set_metadata`, which callers
+compute only while `recording()` says a profiler session records.  A span
+opened with `cpu=True` also reports `cpu_ns`, the calling thread's CPU
+time inside it: wall time less what the thread spent blocked, on the
+device or otherwise.
 
-The DISABLED tracer (the default everywhere) must cost nothing on the
-ingest hot loop: `Tracer(enabled=False).span(...)` returns one shared
-`_NullSpan` whose `sync` is identity — no timestamp read, no allocation,
-and crucially ZERO added `block_until_ready` calls or kernel launches
-(spy-tested in tests/test_obs.py).
+A span never blocks: it adds no wait on the device, no device-to-host
+read and no dispatch.  With no session running it costs one
+`TraceAnnotation.is_enabled()` check and records nothing; the profiler
+keeps recorded spans in memory and writes them out when its session ends
+(`jax.profiler.trace(dir)` / `start_trace` .. `stop_trace`).
 """
 from __future__ import annotations
 
 import time
-from typing import Any, Optional
+
+from jax.profiler import TraceAnnotation
+
+PREFIX = "cms."
+
+recording = TraceAnnotation.is_enabled
 
 
-class _NullSpan:
-    """Shared no-op span: the disabled tracer's entire overhead."""
+class _Off:
+    """The span handed out while no profiler session records: inert."""
 
     __slots__ = ()
 
-    def __enter__(self) -> "_NullSpan":
+    def __enter__(self) -> "_Off":
         return self
 
     def __exit__(self, *exc) -> None:
         return None
 
-    def sync(self, arrays: Any) -> Any:
-        return arrays
+    def set_metadata(self, **counts) -> None:
+        return None
 
 
-_NULL_SPAN = _NullSpan()
+_OFF = _Off()
 
 
-class Span:
-    """One timed region.  Duration runs from __enter__ to __exit__; call
-    `sync(arrays)` on the region's outputs so the closing timestamp sits
-    at a block_until_ready boundary (un-synced spans still record, but
-    only measure host-side dispatch time — `synced` says which)."""
+class _CpuSpan(TraceAnnotation):
+    """A recording span that adds `cpu_ns` at close."""
 
-    __slots__ = ("tracer", "name", "meta", "t0", "synced")
-
-    def __init__(self, tracer: "Tracer", name: str, meta: dict):
-        self.tracer = tracer
-        self.name = name
-        self.meta = meta
-        self.t0 = 0.0
-        self.synced = False
-
-    def __enter__(self) -> "Span":
-        self.t0 = time.perf_counter()
+    def __enter__(self) -> "_CpuSpan":
+        super().__enter__()
+        self._cpu0 = time.thread_time_ns()
         return self
 
-    def sync(self, arrays: Any) -> Any:
-        import jax  # deferred so the registry/export half stays jax-free
-        jax.block_until_ready(arrays)
-        self.synced = True
-        return arrays
-
     def __exit__(self, *exc) -> None:
-        t1 = time.perf_counter()
-        self.tracer._record(self.name, self.t0, t1, self.synced, self.meta)
+        self.set_metadata(cpu_ns=time.thread_time_ns() - self._cpu0)
+        return super().__exit__(*exc)
 
 
-class Tracer:
-    """Collects spans as chrome://tracing-ready complete events.
-
-    `metrics` (optional, any `MetricsRegistry`) additionally lands every
-    recorded span duration in a per-op log2 histogram
-    (`span_duration_us{span=...}`, 1 us .. ~16.8 s bounds), so p50/p99
-    op latency exports through the same Prometheus text endpoint as the
-    counters — scrape `histogram_quantile` off the cumulative buckets, or
-    read `Histogram.quantile` host-side.  Durations are only meaningful
-    at `Span.sync` boundaries, exactly as for the trace events."""
-
-    def __init__(self, enabled: bool = False, metrics=None):
-        self.enabled = bool(enabled)
-        self.metrics = metrics
-        self.events: list[dict] = []
-        self._epoch = time.perf_counter()
-
-    def span(self, name: str, **meta):
-        """Context manager timing one region (no-op when disabled)."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return Span(self, name, meta)
-
-    def _record(self, name: str, t0: float, t1: float, synced: bool,
-                meta: dict) -> None:
-        args = dict(meta)
-        args["synced"] = synced
-        self.events.append({
-            "name": name,
-            "ts": (t0 - self._epoch) * 1e6,   # chrome traces are in us
-            "dur": (t1 - t0) * 1e6,
-            "args": args,
-        })
-        if self.metrics is not None:
-            # lo=0 -> first bucket <= 1 us, hi=24 -> <= ~16.8 s: spans
-            # outside that land in the clamp/overflow buckets, never lost
-            self.metrics.histogram("span_duration_us", lo=0, hi=24,
-                                   span=name).observe((t1 - t0) * 1e6)
-
-    def clear(self) -> None:
-        self.events.clear()
-        self._epoch = time.perf_counter()
-
-    def summary(self) -> dict[str, dict]:
-        """{span name: {count, total_us, max_us}} — what benchmark JSON
-        embeds as its span-timing metrics block."""
-        out: dict[str, dict] = {}
-        for ev in self.events:
-            s = out.setdefault(ev["name"],
-                               {"count": 0, "total_us": 0.0, "max_us": 0.0})
-            s["count"] += 1
-            s["total_us"] += ev["dur"]
-            s["max_us"] = max(s["max_us"], ev["dur"])
-        return out
+def span(name: str, cpu: bool = False, **counts):
+    """Context manager: the host span `cms.<name>` with `counts` as args,
+    and `cpu_ns` at close if `cpu` (a shared inert span while no profiler
+    session records)."""
+    if not recording():
+        return _OFF
+    return (_CpuSpan if cpu else TraceAnnotation)(PREFIX + name, **counts)

@@ -214,20 +214,19 @@ class _DeviceRing:
 
 
 class _TelemetryMixin:
-    """Per-plane instruments + tracer, shared by both plane kinds.
+    """Per-plane instruments, shared by both plane kinds.
 
     Every plane owns a label in its service's `MetricsRegistry` and keeps
     its ring-occupancy gauge (with automatic high-water), event/flush
     counters, and tenant-count gauge current from the host control path —
     zero device work.  A plane constructed standalone (tests, benchmarks)
-    gets a private registry and a disabled tracer, so the instrument code
-    never branches.
+    gets a private registry, so the instrument code never branches.  The
+    label also names the plane in its `cms.*` host spans.
     """
 
     def _init_telemetry(self, metrics: Optional[obs.MetricsRegistry],
-                        tracer: Optional[obs.Tracer], label: str) -> None:
+                        label: str) -> None:
         self.metrics = metrics if metrics is not None else obs.MetricsRegistry()
-        self.tracer = tracer if tracer is not None else obs.Tracer()
         self.label = label
         self._m_events = self.metrics.counter("plane_events", plane=label)
         self._m_flushes = self.metrics.counter("plane_flushes", plane=label)
@@ -332,15 +331,19 @@ class _TierMixin:
         keeping it authoritative for ALL tenants is what makes demotion
         free of device read-backs); cold tenants touch only the mirror —
         zero device work until they are promoted."""
-        if self.tier is None:
-            self.ring.append(rows, batches)
-            return
-        t = self.tier
-        hot = [i for i, r in enumerate(rows) if t.slot[r] >= 0]
-        if hot:
-            self.ring.append([int(t.slot[rows[i]]) for i in hot],
-                             [batches[i] for i in hot])
-        t.mirror_append(rows, batches)
+        with obs.span("queue_append", plane=self.label,
+                      rows=len(rows)) as sp:
+            if obs.recording():
+                sp.set_metadata(events=sum(int(b.size) for b in batches))
+            if self.tier is None:
+                self.ring.append(rows, batches)
+                return
+            t = self.tier
+            hot = [i for i, r in enumerate(rows) if t.slot[r] >= 0]
+            if hot:
+                self.ring.append([int(t.slot[rows[i]]) for i in hot],
+                                 [batches[i] for i in hot])
+            t.mirror_append(rows, batches)
 
     def _tier_rebalance(self) -> None:
         """Post-flush swap: promote the hottest just-active cold tenants
@@ -382,7 +385,7 @@ class TenantPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
     def __init__(self, spec: SketchSpec, queue_capacity: int, seed: int = 0,
                  track_top: Optional[int] = None,
                  metrics: Optional[obs.MetricsRegistry] = None,
-                 tracer: Optional[obs.Tracer] = None, label: str = "p0",
+                 label: str = "p0",
                  tier: Optional[TierSpec] = None):
         self.spec = spec
         self.tables = jnp.zeros((0, spec.depth, spec.storage_width),
@@ -391,7 +394,7 @@ class TenantPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         self.rng = _RngLane(seed)
         self.names: list[str] = []
         self._init_tracker(track_top)
-        self._init_telemetry(metrics, tracer, label)
+        self._init_telemetry(metrics, label)
         self._init_tier(tier, (spec.depth, spec.storage_width))
 
     @property
@@ -416,7 +419,7 @@ class TenantPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         self._tier_gauges()
         return row
 
-    def flush(self, dense: bool = False) -> int:
+    def flush(self, dense: bool = False, reason: str = "explicit") -> int:
         """Land every tenant's pending events: ONE launch, update + refresh.
 
         The host fill mirror names the R rows with pending fill, and with
@@ -437,7 +440,8 @@ class TenantPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         fills there is exactly one class and the epoch is the same single
         dispatch as before.  `dense=True` forces the legacy two-launch
         whole-plane pipeline (the benchmark baseline and the parity-test
-        oracle).
+        oracle).  `reason` (explicit / pressure / read / watermark) only
+        labels the epoch's `cms.flush_epoch` span.
         """
         pending = self.pending()
         if pending == 0:
@@ -447,64 +451,61 @@ class TenantPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
                 raise ValueError("dense flush is the all-resident baseline "
                                  "pipeline; tiered planes have no resident "
                                  "whole-plane layout to run it on")
-            return self._flush_tiered(pending)
+            return self._flush_tiered(pending, reason)
         rng = self.rng.next()
         active = np.flatnonzero(self.ring.fill).astype(np.int32)
-        tr = self.tracer
-        with tr.span("flush_epoch", plane=self.label,
-                     rows=int(active.size)) as ep:
-            if dense:
-                # two-launch baseline: whole-plane update, then (if
-                # tracking) a fused query refresh over the gathered rows
-                keys, weights = self.ring.live_slice()
-                self.tables = ops.update_many(self.tables, self.spec, keys,
-                                              rng, weights=weights)
-                if self.tracker is not None:
-                    sel = jnp.asarray(active)
-                    self._refresh_topk(active, keys[sel], weights[sel])
-            elif self.tracker is not None:
-                for cols, rows_g in tiering.fill_classes(
-                        self.ring.fill, active, self.ring.queue.shape[1]):
-                    with tr.span("queue_gather", plane=self.label) as sp:
-                        keys, weights = sp.sync(
-                            self.ring.class_slice(rows_g, cols))
-                    rows_d = jnp.asarray(rows_g)
-                    cand, valid = topk.candidates(self._tracker_rows(rows_d),
-                                                  keys, weights > 0)
-                    with tr.span("update_score_rows",
-                                 plane=self.label) as sp:
+        with obs.span("flush_epoch", cpu=True, plane=self.label,
+                      rows=int(active.size), events=pending,
+                      reason=reason) as ep:
+            classes = tiering.fill_classes(self.ring.fill, active,
+                                           self.ring.queue.shape[1])
+            whole = dense or (self.tracker is None and len(classes) == 1
+                              and active.size == len(self.names))
+            if whole:
+                # whole-plane update (the dense baseline, or an untracked
+                # plane whose every row is pending at one fill class)
+                with obs.span("flush.gather", rows=len(self.names),
+                              cols=classes[-1][0]):
+                    keys, weights = self.ring.live_slice()
+                with obs.span("flush.update"):
+                    self.tables = ops.update_many(self.tables, self.spec,
+                                                  keys, rng, weights=weights)
+                if dense and self.tracker is not None:
+                    # two-launch baseline: a fused query refresh over the
+                    # gathered rows
+                    with obs.span("flush.reselect"):
+                        sel = jnp.asarray(active)
+                        self._refresh_topk(active, keys[sel], weights[sel])
+            else:
+                for cols, rows_g in classes:
+                    with obs.span("flush.gather", rows=int(rows_g.size),
+                                  cols=cols):
+                        keys, weights = self.ring.class_slice(rows_g, cols)
+                    if self.tracker is None:
+                        with obs.span("flush.update"):
+                            self.tables = ops.update_rows(
+                                self.tables, self.spec, keys, rng, rows_g,
+                                weights=weights)
+                        continue
+                    with obs.span("flush.candidates"):
+                        rows_d = jnp.asarray(rows_g)
+                        cand, valid = topk.candidates(
+                            self._tracker_rows(rows_d), keys, weights > 0)
+                    with obs.span("flush.update"):
                         self.tables, est = ops.update_score_rows(
                             self.tables, self.spec, keys, rng, rows_g, cand,
                             weights=weights)
-                        sp.sync((self.tables, est))
-                    with tr.span("tracker_reselect", plane=self.label) as sp:
+                    with obs.span("flush.reselect"):
                         self._scatter_tracker(
                             rows_d, topk.reselect(cand, valid, est,
                                                   self.track_top))
-                        sp.sync(self.tracker.keys)
-            else:
-                classes = tiering.fill_classes(self.ring.fill, active,
-                                               self.ring.queue.shape[1])
-                if len(classes) == 1 and active.size == len(self.names):
-                    keys, weights = self.ring.live_slice()
-                    self.tables = ops.update_many(self.tables, self.spec,
-                                                  keys, rng, weights=weights)
-                else:
-                    for cols, rows_g in classes:
-                        with tr.span("queue_gather",
-                                     plane=self.label) as sp:
-                            keys, weights = sp.sync(
-                                self.ring.class_slice(rows_g, cols))
-                        with tr.span("update_rows", plane=self.label) as sp:
-                            self.tables = sp.sync(ops.update_rows(
-                                self.tables, self.spec, keys, rng, rows_g,
-                                weights=weights))
             self.ring.reset()
-            ep.sync(self.tables)
+            if obs.recording():
+                ep.set_metadata(classes=1 if whole else len(classes))
         self._note_flush(pending)
         return pending
 
-    def _flush_tiered(self, pending: int) -> int:
+    def _flush_tiered(self, pending: int, reason: str) -> int:
         """Tiered flush epoch: per fill class, hot tenants land through
         the SAME fused dispatch an all-resident plane issues (uniforms
         drawn from the full-tenant grid via `uniform_rows`, rows mapped
@@ -516,49 +517,50 @@ class TenantPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         rng = self.rng.next()
         total = len(self.names)
         active = np.flatnonzero(t.hfill).astype(np.int32)
-        tr = self.tracer
-        with tr.span("flush_epoch", plane=self.label,
-                     rows=int(active.size)) as ep:
-            for cols, rows_g in tiering.fill_classes(t.hfill, active,
-                                                     t.capw):
+        classes = tiering.fill_classes(t.hfill, active, t.capw)
+        with obs.span("flush_epoch", cpu=True, plane=self.label,
+                      rows=int(active.size), events=pending,
+                      classes=len(classes), reason=reason):
+            for cols, rows_g in classes:
                 slot_g = t.slot[rows_g]
                 hot_g = rows_g[slot_g >= 0]
                 cold_g = rows_g[slot_g < 0]
                 if hot_g.size:
                     slots = t.slot[hot_g].astype(np.int32)
-                    with tr.span("queue_gather", plane=self.label) as sp:
-                        keys, weights = sp.sync(ops.flush_rows_inputs(
+                    with obs.span("flush.gather", rows=int(hot_g.size),
+                                  cols=cols):
+                        keys, weights = ops.flush_rows_inputs(
                             self.ring.queue,
                             t.hfill[hot_g].astype(np.int32),
-                            jnp.asarray(slots), cols))
+                            jnp.asarray(slots), cols)
                     if self.tracker is not None:
-                        rows_d = jnp.asarray(hot_g)
-                        cand, valid = topk.candidates(
-                            self._tracker_rows(rows_d), keys, weights > 0)
-                        with tr.span("update_score_rows",
-                                     plane=self.label) as sp:
+                        with obs.span("flush.candidates"):
+                            rows_d = jnp.asarray(hot_g)
+                            cand, valid = topk.candidates(
+                                self._tracker_rows(rows_d), keys,
+                                weights > 0)
+                        with obs.span("flush.update"):
                             self.tables, est = ops.update_score_rows(
                                 self.tables, self.spec, keys, rng, slots,
                                 cand, weights=weights,
                                 uniform_rows=(total, hot_g))
-                            sp.sync((self.tables, est))
-                        self._scatter_tracker(
-                            rows_d, topk.reselect(cand, valid, est,
-                                                  self.track_top))
+                        with obs.span("flush.reselect"):
+                            self._scatter_tracker(
+                                rows_d, topk.reselect(cand, valid, est,
+                                                      self.track_top))
                     else:
-                        with tr.span("update_rows", plane=self.label) as sp:
-                            self.tables = sp.sync(ops.update_rows(
+                        with obs.span("flush.update"):
+                            self.tables = ops.update_rows(
                                 self.tables, self.spec, keys, rng, slots,
                                 weights=weights,
-                                uniform_rows=(total, hot_g)))
+                                uniform_rows=(total, hot_g))
                 if cold_g.size:
-                    with tr.span("tier_spill", plane=self.label,
-                                 rows=int(cold_g.size)):
+                    with obs.span("tier_spill", plane=self.label,
+                                  rows=int(cold_g.size)):
                         self._tier_spill(cold_g, cols, rng, total)
             self.ring.reset()
             t.note_flush(active)
             self._tier_rebalance()
-            ep.sync(self.tables)
         self._note_flush(pending)
         return pending
 
@@ -677,7 +679,7 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
     def __init__(self, wspec: w.WindowSpec, queue_capacity: int,
                  seed: int = 0, track_top: Optional[int] = None,
                  metrics: Optional[obs.MetricsRegistry] = None,
-                 tracer: Optional[obs.Tracer] = None, label: str = "w0",
+                 label: str = "w0",
                  tier: Optional[TierSpec] = None):
         self.wspec = wspec
         s = wspec.sketch
@@ -695,7 +697,7 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         # ingest hot path
         self.epochs: list[Optional[int]] = []
         self._init_tracker(track_top)
-        self._init_telemetry(metrics, tracer, label)
+        self._init_telemetry(metrics, label)
         self._m_rotations = self.metrics.counter("plane_rotations",
                                                  plane=label)
         # one masked device op per advance_many that rotated anything —
@@ -812,10 +814,10 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         if pend:
             flush_cb()  # rebinds self.tables: rotation reads the new leaf
         if self.tier is None:
-            with self.tracer.span("window_rotate", plane=self.label,
-                                  rows=int(rot.size)) as sp:
-                self.tables = sp.sync(ops.window_advance_rows(
-                    self.tables, self.cursors, steps))
+            with obs.span("window_rotate", plane=self.label,
+                          rows=int(rot.size)):
+                self.tables = ops.window_advance_rows(
+                    self.tables, self.cursors, steps)
             self._m_rotation_dispatches.inc()
         else:
             # hot tenants rotate on the slot-indexed device leaf in one
@@ -824,10 +826,10 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
             t_ = self.tier
             st = t_.slot_tenant
             if st.size and steps[st].any():
-                with self.tracer.span("window_rotate", plane=self.label,
-                                      rows=int(rot.size)) as sp:
-                    self.tables = sp.sync(ops.window_advance_rows(
-                        self.tables, self.cursors[st], steps[st]))
+                with obs.span("window_rotate", plane=self.label,
+                              rows=int(rot.size)):
+                    self.tables = ops.window_advance_rows(
+                        self.tables, self.cursors[st], steps[st])
                 self._m_rotation_dispatches.inc()
             for row in rot:
                 if t_.slot[row] < 0:
@@ -840,7 +842,7 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
             self._g_epoch[row].set(self.epochs[row])
         self._m_rotations.inc(int(steps.sum()))
 
-    def flush(self, dense: bool = False) -> int:
+    def flush(self, dense: bool = False, reason: str = "explicit") -> int:
         """Land every pending tenant's events in its ACTIVE bucket —
         straight on the native leaf, zero restack copies.
 
@@ -856,7 +858,7 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         kept as the parity oracle and benchmark baseline).  The tracker
         refresh scores candidates through the row-mapped stacked window
         query, so rotation, expiry, and decay reorder the heap alongside
-        the new mass.
+        the new mass.  `reason` only labels the `cms.flush_epoch` span.
         """
         pending = self.pending()
         if pending == 0:
@@ -866,45 +868,48 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
                 raise ValueError("dense flush is the all-resident baseline "
                                  "pipeline; tiered planes have no resident "
                                  "whole-plane layout to run it on")
-            return self._flush_tiered(pending)
+            return self._flush_tiered(pending, reason)
         rng = self.rng.next()
         t = len(self.names)
         b = self.wspec.buckets
         rows = (np.arange(t, dtype=np.int32) if dense
                 else np.flatnonzero(self.ring.fill).astype(np.int32))
-        tr = self.tracer
-        with tr.span("flush_epoch", plane=self.label,
-                     rows=int(rows.size)) as ep:
+        with obs.span("flush_epoch", cpu=True, plane=self.label,
+                      rows=int(rows.size), events=pending,
+                      reason=reason) as ep:
             kw = None
             if dense:
-                with tr.span("queue_gather", plane=self.label) as sp:
-                    keys, weights = sp.sync(self.ring.live_slice())
+                with obs.span("flush.gather", rows=t):
+                    keys, weights = self.ring.live_slice()
                 # legacy restack pipeline: gather active buckets into an
                 # (R, d, w) stack, dense launch, scatter each bucket back
-                stack = jnp.stack([self.tables[r, self.cursors[r]]
-                                   for r in rows])
-                stack = ops.update_many(stack, self.spec, keys, rng,
-                                        weights=weights,
-                                        uniform_rows=(t, rows))
-                tables = self.tables
-                for i, r in enumerate(rows):
-                    tables = tables.at[r, self.cursors[r]].set(stack[i])
-                self.tables = tables
+                with obs.span("flush.update"):
+                    stack = jnp.stack([self.tables[r, self.cursors[r]]
+                                       for r in rows])
+                    stack = ops.update_many(stack, self.spec, keys, rng,
+                                            weights=weights,
+                                            uniform_rows=(t, rows))
+                    tables = self.tables
+                    for i, r in enumerate(rows):
+                        tables = tables.at[r, self.cursors[r]].set(stack[i])
+                    self.tables = tables
                 kw = (keys, weights)
+                n_classes = 1
             else:
                 classes = tiering.fill_classes(self.ring.fill, rows,
                                                self.ring.queue.shape[1])
+                n_classes = len(classes)
                 flat = self.tables.reshape((t * b,) + self.tables.shape[2:])
                 for cols, rows_g in classes:
-                    with tr.span("queue_gather", plane=self.label) as sp:
-                        keys, weights = sp.sync(
-                            self.ring.class_slice(rows_g, cols))
+                    with obs.span("flush.gather", rows=int(rows_g.size),
+                                  cols=cols):
+                        keys, weights = self.ring.class_slice(rows_g, cols)
                     flat_rows = rows_g * b + self.cursors[rows_g]
-                    with tr.span("window_update", plane=self.label) as sp:
-                        flat = sp.sync(ops.update_rows(
+                    with obs.span("flush.update"):
+                        flat = ops.update_rows(
                             flat, self.spec, keys, rng, flat_rows,
                             weights=weights, uniform_rows=(t, rows_g),
-                            donate=True))
+                            donate=True)
                     if len(classes) == 1:
                         kw = (keys, weights)
                 self.tables = flat.reshape((t, b) + flat.shape[1:])
@@ -913,17 +918,17 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
                     # multi-class epoch: one batch-max re-gather for the
                     # refresh (stale padding is weight-0, so candidacy is
                     # identical to per-class gathers)
-                    with tr.span("queue_gather", plane=self.label) as sp:
-                        kw = sp.sync(self.ring.live_slice(rows))
-                with tr.span("tracker_refresh", plane=self.label) as sp:
+                    with obs.span("flush.gather", rows=int(rows.size)):
+                        kw = self.ring.live_slice(rows)
+                with obs.span("flush.reselect"):
                     self._refresh_topk(rows, *kw)
-                    sp.sync(self.tracker.keys)
             self.ring.reset()
-            ep.sync(self.tables)
+            if obs.recording():
+                ep.set_metadata(classes=n_classes)
         self._note_flush(pending)
         return pending
 
-    def _flush_tiered(self, pending: int) -> int:
+    def _flush_tiered(self, pending: int, reason: str) -> int:
         """Tiered window flush epoch: per fill class, hot tenants land in
         their ACTIVE buckets through the same flat row-mapped dispatch an
         all-resident plane issues (flat row `slot*B + cursor`, uniforms
@@ -936,43 +941,42 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         total = len(self.names)
         b = self.wspec.buckets
         active = np.flatnonzero(t_.hfill).astype(np.int32)
-        tr = self.tracer
-        with tr.span("flush_epoch", plane=self.label,
-                     rows=int(active.size)) as ep:
-            for cols, rows_g in tiering.fill_classes(t_.hfill, active,
-                                                     t_.capw):
+        classes = tiering.fill_classes(t_.hfill, active, t_.capw)
+        with obs.span("flush_epoch", cpu=True, plane=self.label,
+                      rows=int(active.size), events=pending,
+                      classes=len(classes), reason=reason):
+            for cols, rows_g in classes:
                 slot_g = t_.slot[rows_g]
                 hot_g = rows_g[slot_g >= 0]
                 cold_g = rows_g[slot_g < 0]
                 if hot_g.size:
                     slots = t_.slot[hot_g].astype(np.int32)
-                    with tr.span("queue_gather", plane=self.label) as sp:
-                        keys, weights = sp.sync(ops.flush_rows_inputs(
+                    with obs.span("flush.gather", rows=int(hot_g.size),
+                                  cols=cols):
+                        keys, weights = ops.flush_rows_inputs(
                             self.ring.queue,
                             t_.hfill[hot_g].astype(np.int32),
-                            jnp.asarray(slots), cols))
+                            jnp.asarray(slots), cols)
                     h = self.tables.shape[0]
                     flat = self.tables.reshape((h * b,)
                                                + self.tables.shape[2:])
                     flat_rows = slots * b + self.cursors[hot_g]
-                    with tr.span("window_update", plane=self.label) as sp:
-                        flat = sp.sync(ops.update_rows(
+                    with obs.span("flush.update"):
+                        flat = ops.update_rows(
                             flat, self.spec, keys, rng, flat_rows,
                             weights=weights, uniform_rows=(total, hot_g),
-                            donate=True))
+                            donate=True)
                     self.tables = flat.reshape((h, b) + flat.shape[1:])
                 if cold_g.size:
-                    with tr.span("tier_spill", plane=self.label,
-                                 rows=int(cold_g.size)):
+                    with obs.span("tier_spill", plane=self.label,
+                                  rows=int(cold_g.size)):
                         self._tier_spill_window(cold_g, cols, rng, total)
             if self.tracker is not None:
-                with tr.span("tracker_refresh", plane=self.label) as sp:
+                with obs.span("flush.reselect"):
                     self._refresh_topk_tiered(active)
-                    sp.sync(self.tracker.keys)
             self.ring.reset()
             t_.note_flush(active)
             self._tier_rebalance()
-            ep.sync(self.tables)
         self._note_flush(pending)
         return pending
 
@@ -1159,7 +1163,6 @@ class CountService:
                  tenants: Sequence[str] = (), queue_capacity: int = 4096,
                  seed: int = 0, track_top: Optional[int] = None,
                  metrics: Optional[obs.MetricsRegistry] = None,
-                 tracer: Optional[obs.Tracer] = None,
                  probe: Optional[obs.AccuracyProbe] = None,
                  tier: Optional[TierSpec] = None):
         if queue_capacity < 1:
@@ -1176,11 +1179,10 @@ class CountService:
         self._where: dict[str, tuple[object, int]] = {}
         self._order: list[str] = []
         self._admission: dict[str, adm.AdmissionSpec] = {}
-        # telemetry plane: one registry + tracer threaded through every
-        # plane; the accuracy probe (opt-in) shadows enqueued keys with
-        # exact host-side counts (see repro.obs)
+        # telemetry plane: one registry threaded through every plane; the
+        # accuracy probe (opt-in) shadows enqueued keys with exact
+        # host-side counts (see repro.obs)
         self.metrics = metrics if metrics is not None else obs.MetricsRegistry()
-        self.tracer = tracer if tracer is not None else obs.Tracer()
         self.probe = probe
         self._m_events = self.metrics.counter("events")
         self._m_flushes = self.metrics.counter("flushes")
@@ -1269,7 +1271,6 @@ class CountService:
                                         self.seed,
                                         track_top=self.track_top,
                                         metrics=self.metrics,
-                                        tracer=self.tracer,
                                         label=f"w{len(self._wplanes)}",
                                         tier=self.tier))
         else:
@@ -1283,7 +1284,6 @@ class CountService:
                     spec, TenantPlane(spec, self.queue_capacity, self.seed,
                                       track_top=self.track_top,
                                       metrics=self.metrics,
-                                      tracer=self.tracer,
                                       label=f"p{len(self._planes)}",
                                       tier=self.tier))
         row = plane.add(name)
@@ -1320,7 +1320,7 @@ class CountService:
 
         For windowed tenants this is the ACTIVE bucket's sketch."""
         plane, row = self._lookup(name)
-        self._flush_plane(plane)
+        self._flush_plane(plane, "read")
         # host cursor/tier mirrors: the tenant's (active-bucket) table is
         # a static slice of its tier's array, no dynamic_index dispatch
         return Sketch(table=plane.table_row(row), spec=plane.spec)
@@ -1339,12 +1339,13 @@ class CountService:
         """
         plane, row = self._lookup(name)
         keys = _as_keys(keys)
-        with self._audited(), self.tracer.span("enqueue", tenant=name) as sp:
+        with self._audited(), obs.span("enqueue", events=int(keys.size)):
             if ts is not None:
                 if not isinstance(plane, WindowPlane):
                     raise ValueError(f"tenant {name!r} is not windowed; "
                                      "register with a WindowSpec to use ts")
-                plane.advance(row, ts, lambda: self._flush_plane(plane))
+                plane.advance(row, ts,
+                              lambda: self._flush_plane(plane, "watermark"))
             if self.probe is not None:
                 self.probe.observe(name, keys)
             self._m_events.inc(int(keys.size))
@@ -1352,13 +1353,12 @@ class CountService:
             while keys.size:
                 free = plane.queue_free(row)
                 if free == 0:
-                    self._flush_plane(plane)
+                    self._flush_plane(plane, "pressure")
                     free = cap
                 take = min(free, keys.size)
                 plane.queue_append_rows([row], [keys[:take]])
                 keys = keys[take:]
             plane.note_append()
-            sp.sync(plane.ring.queue)
 
     def enqueue_many(self, events: dict, ts=None) -> None:
         """Buffer several tenants' microbatches with ONE scatter-append
@@ -1373,8 +1373,12 @@ class CountService:
         """
         by_plane: dict[int, tuple[object, list, list]] = {}
         overflow: list[tuple[str, np.ndarray]] = []
-        with self._audited(), \
-                self.tracer.span("enqueue_many", tenants=len(events)) as sp:
+
+        def n_events() -> int:
+            return sum(int(np.size(k)) for k in events.values())
+
+        with self._audited(), obs.span("enqueue_many", cpu=True,
+                                       tenants=len(events)) as sp:
             if ts is not None:
                 # batch the watermark advances per plane: every boundary
                 # crossing in this call rotates in ONE masked dispatch
@@ -1390,29 +1394,33 @@ class CountService:
                     items.append((row, ts))
                 for plane, items in adv.values():
                     plane.advance_many(
-                        items, lambda p=plane: self._flush_plane(p))
-            for name, keys in events.items():
-                plane, row = self._lookup(name)
-                keys = _as_keys(keys)
-                if keys.size == 0:
-                    continue
-                if keys.size > plane.queue_free(row):
-                    overflow.append((name, keys))
-                    continue
-                _, rows, batches = by_plane.setdefault(id(plane),
-                                                       (plane, [], []))
-                rows.append(row)
-                batches.append(keys)
-                if self.probe is not None:
-                    self.probe.observe(name, keys)
-                self._m_events.inc(int(keys.size))
+                        items,
+                        lambda p=plane: self._flush_plane(p, "watermark"))
+            with obs.span("stage") as st:
+                for name, keys in events.items():
+                    plane, row = self._lookup(name)
+                    keys = _as_keys(keys)
+                    if keys.size == 0:
+                        continue
+                    if keys.size > plane.queue_free(row):
+                        overflow.append((name, keys))
+                        continue
+                    _, rows, batches = by_plane.setdefault(id(plane),
+                                                           (plane, [], []))
+                    rows.append(row)
+                    batches.append(keys)
+                    if self.probe is not None:
+                        self.probe.observe(name, keys)
+                    self._m_events.inc(int(keys.size))
+                if obs.recording():
+                    st.set_metadata(events=n_events())
             for plane, rows, batches in by_plane.values():
                 plane.queue_append_rows(rows, batches)
                 plane.note_append()
-            sp.sync([plane.ring.queue
-                     for plane, _, _ in by_plane.values()])
-        for name, keys in overflow:
-            self.enqueue(name, keys)
+            for name, keys in overflow:
+                self.enqueue(name, keys)
+            if obs.recording():
+                sp.set_metadata(events=n_events(), overflow=len(overflow))
 
     def flush(self) -> int:
         """Land every DIRTY plane's pending events (one fused launch per
@@ -1425,13 +1433,19 @@ class CountService:
         seed), so per-plane state evolves exactly as in a dedicated
         single-spec service.
         """
-        with self._audited():
-            total = sum(plane.flush() for plane in self.dirty_planes)
+        return self._flush_dirty("explicit")
+
+    def _flush_dirty(self, reason: str) -> int:
+        """Land every dirty plane (`flush`, and `query_all`'s read-your-
+        writes flush); `reason` labels the epochs' spans."""
+        dirty = self.dirty_planes
+        with self._audited(), obs.span("flush", planes=len(dirty)):
+            total = sum(plane.flush(reason=reason) for plane in dirty)
         if total:
             self._m_flushes.inc()
         return total
 
-    def _flush_plane(self, plane) -> int:
+    def _flush_plane(self, plane, reason: str) -> int:
         """Scoped flush epoch: land ONE plane's pending events.
 
         The serve-path epoch scheduler — read ops (`query`/`topk`/`admit`/
@@ -1442,10 +1456,11 @@ class CountService:
         always-full-flush one: a skipped clean flush is indistinguishable
         from a landed empty one).  Read-your-writes still holds per
         tenant because every tenant's pending events live in its own
-        plane's ring.
+        plane's ring.  `reason` (pressure / read / watermark) labels the
+        epoch's span.
         """
         with self._audited():
-            total = plane.flush() if plane.pending() else 0
+            total = plane.flush(reason=reason) if plane.pending() else 0
         if total:
             self._m_flushes.inc()
         return total
@@ -1476,16 +1491,28 @@ class CountService:
         reduction over the ring (`window_kw` forwards n_buckets / mode /
         gamma / engine)."""
         plane, row = self._lookup(name)
-        with self._audited(), self.tracer.span("query", tenant=name) as sp:
-            self._flush_plane(plane)
-            probes = jnp.asarray(_as_keys(keys))
+        with self._audited(), obs.span("query", tenant=name) as sp:
+            self._flush_plane(plane, "read")
+            with obs.span("query.upload") as up:
+                probes = jnp.asarray(_as_keys(keys))
+                if obs.recording():
+                    up.set_metadata(probes=int(probes.size))
             if isinstance(plane, WindowPlane):
-                return sp.sync(plane.query_row(row, probes, **window_kw))
-            if window_kw:
-                raise ValueError(f"tenant {name!r} is not windowed; window "
-                                 f"args {sorted(window_kw)} do not apply")
-            return sp.sync(ops.query(Sketch(table=plane.table_row(row),
-                                            spec=plane.spec), probes))
+                with obs.span("query.dispatch"):
+                    out = plane.query_row(row, probes, **window_kw)
+            else:
+                if window_kw:
+                    raise ValueError(f"tenant {name!r} is not windowed; "
+                                     f"window args {sorted(window_kw)} do "
+                                     "not apply")
+                with obs.span("query.row"):
+                    table = plane.table_row(row)
+                with obs.span("query.dispatch"):
+                    out = ops.query(Sketch(table=table, spec=plane.spec),
+                                    probes)
+            if obs.recording():
+                sp.set_metadata(probes=int(probes.size))
+            return out
 
     def query_all(self, keys) -> dict[str, jnp.ndarray]:
         """Estimated counts for EVERY tenant: one fused launch per plane —
@@ -1500,8 +1527,8 @@ class CountService:
         touches them all): read-your-writes.
         """
         with self._audited(), \
-                self.tracer.span("query_all", tenants=len(self._order)) as sp:
-            self.flush()
+                obs.span("query_all", tenants=len(self._order)):
+            self._flush_dirty("read")
             keys = np.asarray(keys)
             per_tenant = keys.ndim == 2
             if per_tenant and keys.shape[0] != len(self._order):
@@ -1519,7 +1546,7 @@ class CountService:
                 est = plane.query_rows(probes)
                 for i, n in enumerate(plane.names):
                     out[n] = est[i]
-            return sp.sync(out)
+            return out
 
     def topk(self, name: str, k: Optional[int] = None, **window_kw):
         """Current top-k heavy hitters of one tenant: (keys, estimates).
@@ -1545,8 +1572,8 @@ class CountService:
         if window_kw and not isinstance(plane, WindowPlane):
             raise ValueError(f"tenant {name!r} is not windowed; "
                              f"window args {sorted(window_kw)} do not apply")
-        with self._audited(), self.tracer.span("topk", tenant=name):
-            self._flush_plane(plane)
+        with self._audited(), obs.span("topk", tenant=name):
+            self._flush_plane(plane, "read")
             keys, est, filled = plane.topk_row(row, **window_kw)
         sel = filled[:k]
         return keys[:k][sel], est[:k][sel]
@@ -1577,8 +1604,8 @@ class CountService:
         if window_kw and not isinstance(plane, WindowPlane):
             raise ValueError(f"tenant {name!r} is not windowed; "
                              f"window args {sorted(window_kw)} do not apply")
-        with self._audited(), self.tracer.span("admit", tenant=name) as sp:
-            self._flush_plane(plane)
+        with self._audited(), obs.span("admit", tenant=name):
+            self._flush_plane(plane, "read")
             if isinstance(plane, WindowPlane):
                 # re-score the heap against the current ring (rotation/
                 # expiry/decay) and persist it — then decide from the
@@ -1587,9 +1614,8 @@ class CountService:
             # tracker leaves sliced on device (no host round trip); ids
             # validate host-side (np) and upload ONCE inside admit_tracked
             tk = plane.tracker
-            return sp.sync(adm.admit_tracked(tk.keys[row], tk.estimates[row],
-                                             tk.filled[row], _as_keys(ids),
-                                             aspec))
+            return adm.admit_tracked(tk.keys[row], tk.estimates[row],
+                                     tk.filled[row], _as_keys(ids), aspec)
 
     # ---- persistence ----
 

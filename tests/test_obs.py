@@ -1,4 +1,4 @@
-"""Telemetry plane: registry, scoped dispatch tallies, tracer, SLO probes.
+"""Telemetry plane: registry, scoped dispatch tallies, spans, SLO probes.
 
 Covers the contracts the observability subsystem promises:
 
@@ -8,8 +8,9 @@ Covers the contracts the observability subsystem promises:
     list.remove would have) and the legacy launch_counts wrappers;
   * the tracked flush epoch auditing as ONE `update_score_rows`
     dispatch under a scoped tally;
-  * the disabled tracer adding ZERO `block_until_ready` calls and ZERO
-    kernel launches to an enqueue/flush loop (spy-tested);
+  * the service's `cms.*` host spans in a CPU `jax.profiler` trace:
+    nested by cause, with the counts sent, never blocking and adding no
+    kernel launches (spy-tested), `serve_counts --trace-out` included;
   * probe exactness + ARE-by-decile, and the accuracy envelope gate
     tripping when a table is corrupted;
   * service metrics (stats parity, ring/watermark gauges) and the
@@ -17,6 +18,7 @@ Covers the contracts the observability subsystem promises:
 """
 import json
 import os
+import time
 
 import jax
 import numpy as np
@@ -122,19 +124,6 @@ def test_prometheus_exposition_shape():
     assert 'accuracy_are_count{tenant="a"} 2' in lines
 
 
-def test_chrome_trace_shape(tmp_path):
-    tr = obs.Tracer(enabled=True)
-    with tr.span("flush_epoch", plane="p0"):
-        pass
-    doc = obs.to_chrome_trace(tr)
-    (ev,) = doc["traceEvents"]
-    assert ev["ph"] == "X" and ev["name"] == "flush_epoch"
-    assert ev["dur"] >= 0 and ev["args"]["plane"] == "p0"
-    path = os.path.join(str(tmp_path), "trace.json")
-    obs.write_chrome_trace(path, tr)
-    assert json.load(open(path))["traceEvents"] == doc["traceEvents"]
-
-
 # --------------------------------------------------------------------------
 # scoped dispatch tallies
 # --------------------------------------------------------------------------
@@ -184,34 +173,105 @@ def test_tracked_flush_epoch_is_one_dispatch_under_scope():
 
 
 # --------------------------------------------------------------------------
-# tracer
+# host spans (jax.profiler annotations)
 # --------------------------------------------------------------------------
 
-def test_tracer_spans_record_and_summarize():
-    tr = obs.Tracer(enabled=True)
-    svc = CountService(SPEC, tenants=("a",), queue_capacity=512, tracer=tr,
+def _record(tmp_path, fn) -> list[dict]:
+    """Run `fn` inside a CPU `jax.profiler` session and return its `cms.*`
+    host spans: name, start/end (ns), counts, and the line (thread)."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("cms."):
+                    spans.append({"name": e.name, "start": e.start_ns,
+                                  "end": e.start_ns + e.duration_ns,
+                                  "args": dict(e.stats),
+                                  "line": (plane.name, line.name)})
+    return sorted(spans, key=lambda s: (s["start"], -s["end"]))
+
+
+def _inside(child: dict, parent: dict) -> bool:
+    return (child["line"] == parent["line"]
+            and parent["start"] <= child["start"]
+            and child["end"] <= parent["end"])
+
+
+def _named(spans, name, **args) -> list[dict]:
+    return [s for s in spans if s["name"] == name
+            and all(s["args"].get(k) == v for k, v in args.items())]
+
+
+def test_tracer_spans_record_and_summarize(tmp_path):
+    """The service's spans land in the profiler's trace, nested by cause:
+    an `enqueue_many` whose batch overflows runs the tenant's own
+    `enqueue`, whose queue-pressure epoch holds its gather, candidates,
+    update and reselect; a read of a dirty plane holds its read epoch.
+    The counts are those of the events and rows sent."""
+    svc = CountService(SPEC, tenants=("a", "b"), queue_capacity=512,
                        track_top=4)
-    svc.enqueue("a", _zipf(200, 80))
-    svc.flush()
-    names = {ev["name"] for ev in tr.events}
-    assert {"enqueue", "flush_epoch", "update_score_rows"} <= names
-    epoch = [ev for ev in tr.events if ev["name"] == "flush_epoch"]
-    assert epoch[0]["args"]["synced"] is True   # closed at a sync boundary
-    summ = tr.summary()
-    assert summ["enqueue"]["count"] == 1
-    assert summ["flush_epoch"]["total_us"] >= summ["flush_epoch"]["max_us"]
-    tr.clear()
-    assert tr.events == []
+    svc.enqueue_many({"a": _zipf(400, 80, seed=1), "b": _zipf(100, 80)})
+
+    def work():
+        # a: 400 buffered + 300 > 512 -> overflow path; b fits
+        svc.enqueue_many({"a": _zipf(300, 80, seed=2),
+                          "b": _zipf(50, 80, seed=3)})
+        svc.query("b", np.arange(16))       # b's plane is dirty again
+
+    spans = _record(tmp_path, work)
+    (many,) = _named(spans, "cms.enqueue_many")
+    assert 0 < many["args"].pop("cpu_ns") <= many["end"] - many["start"]
+    assert many["args"] == {"tenants": 2, "events": 350, "overflow": 1}
+    (stage,) = _named(spans, "cms.stage")
+    assert stage["args"] == {"events": 350} and _inside(stage, many)
+    (enq,) = _named(spans, "cms.enqueue")
+    assert enq["args"] == {"events": 300} and _inside(enq, many)
+    (press,) = _named(spans, "cms.flush_epoch", reason="pressure")
+    assert _inside(press, enq)
+    assert 0 < press["args"].pop("cpu_ns") <= press["end"] - press["start"]
+    # the epoch landed everything buffered: a's 400 + 112 that still fit,
+    # b's 100 + 50
+    assert press["args"] == {"plane": "p0", "rows": 2, "events": 662,
+                             "classes": 1, "reason": "pressure"}
+    for step in ("gather", "candidates", "update", "reselect"):
+        (sp,) = [s for s in _named(spans, f"cms.flush.{step}")
+                 if _inside(s, press)]
+    (gather,) = [s for s in _named(spans, "cms.flush.gather")
+                 if _inside(s, press)]
+    assert gather["args"] == {"rows": 2, "cols": 512}
+    appends = [s for s in _named(spans, "cms.queue_append")
+               if _inside(s, many)]
+    assert sum(s["args"]["events"] for s in appends) == 350
+    assert {s["args"]["plane"] for s in appends} == {"p0"}
+
+    (q,) = _named(spans, "cms.query")
+    assert q["args"]["tenant"] == "b" and q["args"]["probes"] == 16
+    (read,) = _named(spans, "cms.flush_epoch", reason="read")
+    assert _inside(read, q) and read["args"]["events"] == 188
+    for step in ("upload", "row", "dispatch"):
+        (sp,) = _named(spans, f"cms.query.{step}")
+        assert _inside(sp, q)
+    # every epoch step lies inside an epoch
+    epochs = _named(spans, "cms.flush_epoch")
+    for s in spans:
+        if s["name"].startswith("cms.flush."):
+            assert any(_inside(s, ep) for ep in epochs), s
 
 
-def test_disabled_tracer_costs_nothing():
-    """The no-op tracer path: an enqueue/flush loop must add ZERO
-    block_until_ready calls and ZERO kernel launches vs the span-free
-    baseline (the null span's sync is identity)."""
+def test_disabled_tracer_costs_nothing(tmp_path):
+    """Spans never block: an enqueue/flush/query loop adds ZERO
+    block_until_ready calls, and issues the same kernel launches with and
+    without a recording profiler session."""
     def loop(svc):
         for i in range(3):
             svc.enqueue("a", _zipf(200, 80, seed=i))
+            svc.enqueue_many({"a": _zipf(100, 80, seed=10 + i)})
             svc.flush()
+            svc.query("a", np.arange(8))
 
     blocks = []
     orig_block = jax.block_until_ready
@@ -220,28 +280,94 @@ def test_disabled_tracer_costs_nothing():
         blocks.append(1)
         return orig_block(x)
 
-    svc_off = CountService(SPEC, tenants=("a",), queue_capacity=512,
-                           track_top=4)   # default tracer: disabled
-    assert svc_off.tracer.enabled is False
-    try:
-        jax.block_until_ready = spy_block
-        with ops.audit_scope() as tally_off:
-            loop(svc_off)
-    finally:
-        jax.block_until_ready = orig_block
-    assert blocks == []                   # zero added sync points
+    tallies = []
+    for recording in (False, True):
+        svc = CountService(SPEC, tenants=("a",), queue_capacity=512,
+                           track_top=4)
+        try:
+            jax.block_until_ready = spy_block
+            with ops.audit_scope() as tally:
+                if recording:
+                    spans = _record(tmp_path, lambda: loop(svc))
+                else:
+                    loop(svc)
+        finally:
+            jax.block_until_ready = orig_block
+        tallies.append(dict(tally))
+    assert blocks == []                    # zero added sync points
+    assert tallies[0] == tallies[1]        # recording adds no launches
+    assert len(_named(spans, "cms.query")) == 3
 
-    # identical loop with tracing on: same kernel launches, >0 syncs
-    svc_on = CountService(SPEC, tenants=("a",), queue_capacity=512,
-                          track_top=4, tracer=obs.Tracer(enabled=True))
-    try:
-        jax.block_until_ready = spy_block
-        with ops.audit_scope() as tally_on:
-            loop(svc_on)
-    finally:
-        jax.block_until_ready = orig_block
-    assert blocks != []
-    assert dict(tally_off) == dict(tally_on)  # tracing adds no launches
+
+def test_cpu_span_leaves_out_blocked_time(tmp_path):
+    """A `cpu=True` span's `cpu_ns` is the calling thread's CPU time
+    inside it: a sleep adds wall time and next to no CPU time, a busy loop
+    adds both."""
+    def work():
+        with obs.span("enqueue_many", cpu=True, tenants=0):
+            time.sleep(0.05)
+        with obs.span("flush_epoch", cpu=True):
+            stop = time.thread_time_ns() + 20_000_000
+            while time.thread_time_ns() < stop:
+                pass
+
+    spans = _record(tmp_path, work)
+    (sleep,) = _named(spans, "cms.enqueue_many")
+    (busy,) = _named(spans, "cms.flush_epoch")
+    assert sleep["end"] - sleep["start"] >= 50_000_000
+    assert sleep["args"]["cpu_ns"] < 10_000_000
+    assert 20_000_000 <= busy["args"]["cpu_ns"] <= busy["end"] - busy["start"]
+
+
+def test_span_without_session_records_nothing(tmp_path):
+    """Outside a profiler session a span is one shared inert object that
+    drops its counts; inside one it is a `cms.`-named annotation."""
+    assert not obs.recording()
+    with obs.span("flush_epoch", plane="p0") as sp:
+        sp.set_metadata(events=1)
+    assert sp is obs.span("query")
+    seen = []
+
+    def work():
+        assert obs.recording()
+        seen.append(obs.span("query", tenant="a"))
+    spans = _record(tmp_path, work)
+    assert isinstance(seen[0], jax.profiler.TraceAnnotation)
+    assert spans == []          # made but never entered: nothing recorded
+
+
+def test_watermark_epoch_nests_in_its_enqueue(tmp_path):
+    """A windowed tenant's interval crossing flushes inside the call that
+    crossed it (`reason="watermark"`) and rotates in one `window_rotate`."""
+    wspec = WindowSpec(sketch=SPEC, buckets=4, interval=10.0)
+    svc = CountService(queue_capacity=512, track_top=4)
+    svc.add_tenant("w", window=wspec)
+    svc.enqueue_many({"w": _zipf(100, 20)}, ts=5.0)
+    spans = _record(tmp_path, lambda: svc.enqueue_many(
+        {"w": _zipf(100, 20, seed=1)}, ts=25.0))
+    (many,) = _named(spans, "cms.enqueue_many")
+    (ep,) = _named(spans, "cms.flush_epoch", reason="watermark")
+    (rot,) = _named(spans, "cms.window_rotate")
+    assert _inside(ep, many) and _inside(rot, many)
+    assert ep["args"]["plane"] == "w0" and ep["args"]["events"] == 100
+    assert rot["args"] == {"plane": "w0", "rows": 1}
+    assert ep["end"] <= rot["start"]     # flush first, then rotate
+
+
+def test_serve_counts_trace_out_writes_profiler_trace(tmp_path):
+    """`serve_counts --trace-out DIR` records a `jax.profiler` trace whose
+    host plane holds the service's `cms.*` spans."""
+    from jax.profiler import ProfileData
+    from repro.launch import serve_counts
+    out = tmp_path / "trace"
+    serve_counts.main(["--tenants", "2", "--batches", "2", "--batch", "256",
+                       "--width", "1024", "--depth", "2", "--queue-cap",
+                       "512", "--trace-out", str(out)])
+    (path,) = out.rglob("*.xplane.pb")
+    names = {e.name for p in ProfileData.from_file(str(path)).planes
+             for line in p.lines for e in line.events}
+    assert {"cms.enqueue_many", "cms.flush_epoch", "cms.query_all",
+            "cms.query", "cms.topk", "cms.admit"} <= names
 
 
 # --------------------------------------------------------------------------

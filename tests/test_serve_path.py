@@ -34,7 +34,7 @@ PROBES = np.arange(32, dtype=np.uint32)
 class FullFlushService(CountService):
     """The pre-scheduler oracle: every scoped flush sweeps every plane."""
 
-    def _flush_plane(self, plane):
+    def _flush_plane(self, plane, reason):
         return self.flush()
 
 
