@@ -191,6 +191,48 @@ def query_many(tables: jnp.ndarray, spec: sk.SketchSpec, keys: jnp.ndarray
                               cpl=spec.cells_per_lane)
 
 
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _query_row_xla(tables, row, keys, *, spec):
+    # the eager `sk.query` arithmetic over row `row` of the stack, with the
+    # row inside the gather: no per-row table is sliced out first
+    cols = sk.row_hashes(keys, _row_seeds_array(spec), spec.width)  # (d, N)
+    rows = jnp.arange(spec.depth)[:, None]
+    if spec.packed:
+        vals = sk.logical_table(tables[row], spec)[rows, cols]
+    else:
+        vals = tables[row, rows, cols]
+    return spec.counter.decode(vals.min(axis=0))
+
+
+@functools.lru_cache(maxsize=4096)
+def _row_index(row: int) -> jax.Array:
+    # a row index as a device int32 scalar, uploaded once: handed over as a
+    # host scalar it would cost every read a transfer of its own
+    return jax.device_put(np.int32(row))
+
+
+def query_row(tables: jnp.ndarray, spec: sk.SketchSpec, row: int,
+              keys: jnp.ndarray) -> jnp.ndarray:
+    """One tenant's query over a (T, d, w) stack: keys (N,) -> float32 (N,).
+
+    Off-TPU within VMEM the Pallas query over the row's table.  Otherwise
+    ONE jitted XLA program that hashes, gathers `tables[row]`'s cells, takes
+    the min and decodes; the row is a traced argument, so every row of a
+    stack shares one compile per probe length.  Bit-identical to
+    `sk.query` on `tables[row]`.  Tallied as "query".
+    """
+    if _kernel_auto(spec):
+        _launch("query", "kernel")
+        return query_pallas(tables[row], keys, seeds=_seeds_tuple(spec),
+                            width=spec.width, counter=spec.counter,
+                            interpret=_interpret(),
+                            cpl=spec.cells_per_lane)
+    _launch("query", "xla")
+    if isinstance(row, (int, np.integer)):
+        row = _row_index(int(row))
+    return _query_row_xla(tables, row, keys, spec=spec)
+
+
 def window_query_tables(tables: jnp.ndarray, spec: sk.SketchSpec,
                         keys: jnp.ndarray, weights: jnp.ndarray,
                         mode: str = "sum", engine: str = "auto"

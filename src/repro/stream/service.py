@@ -642,6 +642,17 @@ class TenantPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
                     jnp.asarray(t.cold[cold]), self.spec, ck))
         return jnp.asarray(out)
 
+    def query_row(self, row: int, keys: jnp.ndarray) -> jnp.ndarray:
+        """Estimates for one tenant: ONE `ops.query_row` over the device
+        stack at the tenant's row (its slot, when tiered); a cold tenant's
+        host row goes up as a one-row stack and is read as row 0."""
+        tables, at = self.tables, row
+        if self.tier is not None:
+            at = int(self.tier.slot[row])
+            if at < 0:
+                tables, at = jnp.asarray(self.tier.cold[row][None]), 0
+        return ops.query_row(tables, self.spec, at, keys)
+
     def table_row(self, row: int) -> jnp.ndarray:
         """One tenant's table in the all-resident layout (hot tenants
         slice the device stack at their slot; cold tenants upload their
@@ -1486,30 +1497,23 @@ class CountService:
         first — read-your-writes without paying other planes' epochs; a
         clean plane costs zero update dispatches).
 
-        Plain tenants: one fused-kernel launch (the T=1 case of
-        `query_all`'s kernel).  Windowed tenants: the fused window
+        Plain tenants: one `ops.query_row` dispatch over the plane's
+        stack at the tenant's row (on TPU one jitted program; off-TPU
+        within VMEM the Pallas query).  Windowed tenants: the fused window
         reduction over the ring (`window_kw` forwards n_buckets / mode /
         gamma / engine)."""
         plane, row = self._lookup(name)
+        if window_kw and not isinstance(plane, WindowPlane):
+            raise ValueError(f"tenant {name!r} is not windowed; window "
+                             f"args {sorted(window_kw)} do not apply")
         with self._audited(), obs.span("query", tenant=name) as sp:
             self._flush_plane(plane, "read")
             with obs.span("query.upload") as up:
                 probes = jnp.asarray(_as_keys(keys))
                 if obs.recording():
                     up.set_metadata(probes=int(probes.size))
-            if isinstance(plane, WindowPlane):
-                with obs.span("query.dispatch"):
-                    out = plane.query_row(row, probes, **window_kw)
-            else:
-                if window_kw:
-                    raise ValueError(f"tenant {name!r} is not windowed; "
-                                     f"window args {sorted(window_kw)} do "
-                                     "not apply")
-                with obs.span("query.row"):
-                    table = plane.table_row(row)
-                with obs.span("query.dispatch"):
-                    out = ops.query(Sketch(table=table, spec=plane.spec),
-                                    probes)
+            with obs.span("query.dispatch"):
+                out = plane.query_row(row, probes, **window_kw)
             if obs.recording():
                 sp.set_metadata(probes=int(probes.size))
             return out

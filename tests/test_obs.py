@@ -252,14 +252,34 @@ def test_tracer_spans_record_and_summarize(tmp_path):
     assert q["args"]["tenant"] == "b" and q["args"]["probes"] == 16
     (read,) = _named(spans, "cms.flush_epoch", reason="read")
     assert _inside(read, q) and read["args"]["events"] == 188
-    for step in ("upload", "row", "dispatch"):
+    for step in ("upload", "dispatch"):
         (sp,) = _named(spans, f"cms.query.{step}")
         assert _inside(sp, q)
+    assert _named(spans, "cms.query.row") == []
     # every epoch step lies inside an epoch
     epochs = _named(spans, "cms.flush_epoch")
     for s in spans:
         if s["name"].startswith("cms.flush."):
             assert any(_inside(s, ep) for ep in epochs), s
+
+
+def test_clean_read_spans_upload_and_dispatch(tmp_path):
+    """A plain tenant's clean read records `cms.query` holding its upload
+    and its one dispatch, in that order, and nothing else: no epoch and
+    no separate table slice."""
+    svc = CountService(SPEC, tenants=("a", "b"), queue_capacity=512)
+    svc.enqueue_many({"a": _zipf(200, 80), "b": _zipf(200, 80, seed=4)})
+    svc.flush()
+    spans = _record(tmp_path, lambda: svc.query("a", np.arange(24)))
+    (q,) = _named(spans, "cms.query")
+    assert q["args"] == {"tenant": "a", "probes": 24}
+    (up,) = _named(spans, "cms.query.upload")
+    (disp,) = _named(spans, "cms.query.dispatch")
+    assert up["args"] == {"probes": 24}
+    assert _inside(up, q) and _inside(disp, q)
+    assert up["end"] <= disp["start"]
+    assert {s["name"] for s in spans} == {"cms.query", "cms.query.upload",
+                                          "cms.query.dispatch"}
 
 
 def test_disabled_tracer_costs_nothing(tmp_path):

@@ -332,3 +332,127 @@ def test_windowed_query_all_tiered_matches_per_tenant():
         np.testing.assert_array_equal(
             np.asarray(out[n]), np.asarray(svc.query(n, PROBES)),
             err_msg=f"tiered stacked query_all diverged on {n}")
+
+
+# --------------------------------------------------------------------------
+# one program per plain read: `TenantPlane.query_row` / `ops.query_row`
+# --------------------------------------------------------------------------
+
+READ_SPECS = {
+    "cms32": SketchSpec(width=1024, depth=2, counter=CMS32),
+    "log16": SketchSpec(width=1024, depth=2, counter=CMLS16),
+    "log16_packed": SketchSpec(width=1024, depth=2, counter=CMLS16,
+                               packed=True),
+    "log8_packed": SketchSpec(width=1024, depth=2, counter=CMLS8,
+                              packed=True),
+}
+READ_NAMES = [f"r{i}" for i in range(7)]
+_FILLED: dict = {}
+
+
+def _filled(spec_id: str, tiered: bool) -> CountService:
+    """A flushed seven-tenant service (tiered: three hot slots), built
+    once per spec and layout."""
+    key = (spec_id, tiered)
+    if key not in _FILLED:
+        svc = CountService(READ_SPECS[spec_id], tenants=READ_NAMES,
+                           queue_capacity=2048, seed=3, track_top=4,
+                           tier=TierSpec(max_hot_tenants=3) if tiered
+                           else None)
+        rng = np.random.default_rng(17)
+        for r in range(3):
+            group = READ_NAMES if r == 0 else READ_NAMES[r::2]
+            svc.enqueue_many({n: _batch(rng, 500, 3_000) for n in group})
+            svc.flush()
+        _FILLED[key] = svc
+    return _FILLED[key]
+
+
+def _tenant_at(svc: CountService, placement: str, position: str) -> str:
+    """The first, middle or last tenant of a placement: every row of an
+    all-resident plane, or the hot slots / cold tenants of a tiered one."""
+    plane = svc.planes[0]
+    if placement == "resident":
+        rows = list(range(len(plane.names)))
+    else:
+        hot = plane.tier.slot >= 0
+        rows = list(np.flatnonzero(hot if placement == "hot" else ~hot))
+    assert rows, f"no {placement} tenant"
+    pick = {"first": 0, "middle": len(rows) // 2, "last": -1}[position]
+    return plane.names[int(rows[pick])]
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Engine selection as it runs on a TPU backend."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("engine", ["xla", "kernel"])
+@pytest.mark.parametrize("position", ["first", "middle", "last"])
+@pytest.mark.parametrize("placement", ["resident", "hot", "cold"])
+@pytest.mark.parametrize("spec_id", sorted(READ_SPECS))
+def test_plain_read_bit_identical_to_eager_query(monkeypatch, spec_id,
+                                                 placement, position,
+                                                 engine):
+    """A plain tenant's read is byte for byte the eager `sk.query` over its
+    table, on either engine, for every cell format and tier placement."""
+    import jax.numpy as jnp
+    from repro.core import sketch as sk
+    svc = _filled(spec_id, placement != "resident")
+    name = _tenant_at(svc, placement, position)
+    plane, row = svc._lookup(name)
+    keys = np.arange(0, 3_000, 7, dtype=np.uint32)
+    want = sk.query(sk.Sketch(table=plane.table_row(row), spec=plane.spec),
+                    jnp.asarray(keys))
+    monkeypatch.setattr(ops, "on_tpu", lambda: engine == "xla")
+    with ops.audit_scope() as tally:
+        got = svc.query(name, keys)
+    assert dict(tally) == {"query": 1}
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype == np.float32
+    assert got.tobytes() == want.tobytes(), f"{name} diverged"
+    assert float(want.max()) > 0
+
+
+def test_clean_read_is_one_xla_program(as_tpu):
+    """On the TPU engine a clean read is exactly one `query` dispatch on
+    the XLA engine; a read of a dirty plane lands its epoch first and
+    sees its writes."""
+    svc = CountService(_spec(), tenants=["a0", "a1"], queue_capacity=2048,
+                       seed=5, track_top=4)
+    rng = np.random.default_rng(3)
+    svc.enqueue_many({n: _batch(rng) for n in svc.tenants})
+    svc.flush()
+    scope = ops.audit_scope()
+    with scope:
+        svc.query("a1", PROBES)
+    assert dict(scope.engines) == {("query", "xla"): 1}
+    assert _update_ops(scope.tally) == {}
+
+    svc.enqueue("a0", np.full(300, 1234, np.uint32))
+    scope = ops.audit_scope()
+    with scope:
+        est = np.asarray(svc.query("a0", np.asarray([1234], np.uint32)))
+    assert scope.engines[("query", "xla")] == 1
+    assert _update_ops(scope.tally) == {"update_score_rows": 1}
+    assert est[0] >= 250, "the read must see its plane's pending writes"
+    assert svc.dirty_planes == []
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_plain_reads_share_one_compile(as_tpu, packed):
+    """Every row of a 16-tenant plane, read twice over, runs one compiled
+    program: the row is a traced argument, not part of the shape."""
+    spec = SketchSpec(width=1152, depth=3, counter=CMLS16, packed=packed)
+    names = [f"c{i:02d}" for i in range(16)]
+    svc = CountService(spec, tenants=names, queue_capacity=1024, seed=2)
+    rng = np.random.default_rng(8)
+    svc.enqueue_many({n: _batch(rng, 200) for n in names})
+    svc.flush()
+    probes = np.arange(41, dtype=np.uint32)
+    before = ops._query_row_xla._cache_size()
+    for _ in range(2):
+        for n in names:
+            svc.query(n, probes)
+    assert ops._query_row_xla._cache_size() - before == 1

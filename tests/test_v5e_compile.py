@@ -146,6 +146,18 @@ def test_query_stacked_compiles(as_tpu, one_chip, spec):
     assert engines == {("query_many", "jnp"): 1}
 
 
+def test_query_row_compiles_at_cell_shapes(as_tpu, one_chip):
+    """A plain tenant's read at the PMI deployment's shapes (64 tenants of
+    2 x 2^20 CMLS16 cells, 3,072 probes) is one XLA program that gathers
+    straight from the stack: no row is sliced out first."""
+    spec = SketchSpec(width=1 << 20, depth=2, counter=CMLS16)
+    compiled, engines = _compile(
+        lambda t, r, k: ops.query_row(t, spec, r, k), one_chip,
+        _tables(spec, 64), ((), jnp.int32), ((3072,), jnp.uint32))
+    assert engines == {("query", "xla"): 1}
+    assert "dynamic-slice" not in compiled.as_text()
+
+
 def test_window_query_stacked_rows_compiles(as_tpu, one_chip):
     spec = _spec()
     leaf = ((1, BUCKETS, spec.depth, spec.storage_width), spec.storage_dtype)
@@ -222,6 +234,8 @@ def test_auto_never_reaches_pallas_on_tpu(as_tpu, monkeypatch):
         svc.topk("a", 3)
         svc.topk("w", 3)
         svc.query("w", probe, gamma=0.5)
+        for t in ("a", "b", "c", "m"):     # hot slots, a cold tenant
+            svc.query(t, probe)
 
         s = init(small)
         keys = jnp.asarray(probe)
