@@ -1,11 +1,35 @@
 """One cell of the chip benchmark: build the deployment's `CountService`, warm
 its shapes, measure a window of its traffic, and check the answers.
 
-Everything a cell needs is data: its configuration (`configs/<name>.json`:
-planes, specs, stream), its traffic mix (`traffic/<name>.json`: the loop
-kind and its parameters), the limits of its comparison
-(`limits/<cell>.json`), and a reader per per-layer metric
-(`metrics/<name>.py`).  The loop kinds here are general:
+Everything a cell needs is found by name under the cells directory (this
+file's, or one handed to `run_cell`): its configuration
+(`configs/<config>.json`: planes, specs, stream), its traffic mix
+(`traffic/<traffic>.json`: the loop kind and its parameters), the limits of
+its comparison (`limits/<cell>.json`), and a reader per per-layer metric
+(`metrics/<name>.py`).  Two lookups let a deployment bring its own code as
+files, with no edit here:
+
+  deployment  `deploy/<config>.py`, where it exists, defines
+              `build(cfg, seed, counter) -> (svc, names)`; else
+              `build_service` builds the configuration's planes.  `counter`
+              is None, or the configuration's `control_counter` in a
+              control run, to stand in for each plane's own counter.
+  loop kind   the traffic's `loop` names one of `LOOPS` below, else the one
+              subclass of `Loop` that `loops/<loop>.py` defines, with its
+              own `setup`, `warm`, `op` (or `run`), `compare` and, where
+              the inherited epoch count does not fit, `least_bytes`.  A
+              kind found in neither stops the run before its set-up, with
+              no result.
+
+What the harness reads of a built `svc` (the whole contract): `svc.planes`,
+each plane with `spec` (a `SketchSpec`: `counter.bits`, `packed`,
+`storage_dtype`, `depth`), `names` (its tenants), `label`, and `tables` and
+`tracker` (waited on by `Loop.drain`); and the counter
+`svc.metrics.counter("plane_flushes", plane=label)` that each flush epoch
+moves.  `names` lists every tenant once, each in some plane's `names`;
+the loop's tenant t is `names[t]`.
+
+The built-in loop kinds:
 
   closed_ingest  one `enqueue_many` per call, every tenant, as fast as the
                  service takes it; the window ends once a last `flush` has
@@ -13,9 +37,10 @@ kind and its parameters), the limits of its comparison
   open_read      a prefilled service; reads `query(tenant, keys)` due at
                  Poisson times at a fixed rate, timed from their due time.
 
-The program's tracer stays disabled (an enabled span blocks on the device)
-and its accuracy probe off; the harness times its own calls on the host
-clock and, in a traced run, marks them with `TraceAnnotation`s.
+The program's accuracy probe is off.  Its own `cms.*` spans never block
+and record only while a profiler session runs (`--trace 1`); the harness
+times its own calls on the host clock and, in a traced run, marks them with
+`TraceAnnotation`s.
 """
 from __future__ import annotations
 
@@ -38,6 +63,7 @@ HERE = pathlib.Path(__file__).resolve().parent
 
 TRACE_LEAD_S = 1.0   # steady window before the traced span
 TRACE_SPAN_S = 3.0   # traced span of a --trace 1 run
+SCHEDULE_SEED = 0    # the open loop's arrival order, the same for every seed
 
 
 def load_json(path: pathlib.Path) -> dict:
@@ -133,7 +159,7 @@ def tenant_names(plane: dict) -> list[str]:
 
 def build_service(cfg: dict, seed: int, counter: dict | None = None):
     """The configuration's `CountService`, tenants registered plane by plane
-    (so registry order is plane order), tracer disabled, probe off."""
+    (so registry order is plane order), probe off."""
     from repro.core.sketch import SketchSpec
     from repro.stream import CountService
     svc = CountService(queue_capacity=int(cfg["queue_capacity"]),
@@ -282,8 +308,7 @@ class Loop:
         out = {"events_per_s": (self.rec["events"] / window_s
                                 if self.rec["events"] else None)}
         lat = self.rec["lat_s"]
-        out["read_p95_ms"] = (float(np.percentile(lat, 95)) * 1e3
-                              if lat else None)
+        out["reads_per_s"] = len(lat) / window_s if lat else None
         return out
 
 
@@ -361,11 +386,11 @@ class OpenRead(Loop):
         _, self.streams = corpus_streams(self.rng, self.cfg["stream"],
                                          len(self.names))
         T, L = self.streams.shape
-        # probe sets: a uniformly chosen tenant, and for each of
-        # `read_bigrams` positions of its stream the bigram and both its
-        # unigrams (events 2i, 2i+1, 2i+2)
+        # probe sets: every tenant equally often, in an order drawn from
+        # the seed, and for each of `read_bigrams` positions of its stream
+        # the bigram and both its unigrams (events 2i, 2i+1, 2i+2)
         R, m = int(self.trf["pool_reads"]), int(self.trf["read_bigrams"])
-        self.read_tenant = self.rng.integers(0, T, size=R)
+        self.read_tenant = self.rng.permutation(np.arange(R) % T)
         pos = self.rng.integers(0, (L - 3) // 2, size=(R, m)) * 2
         idx = pos[:, :, None] + np.arange(3)
         self.read_keys = np.stack(
@@ -397,13 +422,15 @@ class OpenRead(Loop):
         self.drain()
 
     def schedule(self, seconds: float) -> np.ndarray:
-        """Due times (s from the window's start): the same set of
-        exponential gaps in every run, in an order drawn from the seed, so
-        seeds change the arrivals' order and not their number."""
+        """Due times (s from the window's start): one fixed order of one
+        set of exponential gaps, the same in every run whatever the seed,
+        so that seeds change which reads are due and not when.  Above
+        capacity the backlog's age follows the arrivals' order closely."""
         k = int(np.ceil(seconds * self.rate * 1.25)) + 16
         q = (np.arange(k) + 0.5) / k
         gaps = -np.log1p(-q) / self.rate
-        return np.cumsum(self.rng.permutation(gaps))
+        order = np.random.default_rng(SCHEDULE_SEED).permutation(k)
+        return np.cumsum(gaps[order])
 
     def run(self, seconds: float) -> None:
         due = self.schedule(seconds)
@@ -458,6 +485,41 @@ class OpenRead(Loop):
 LOOPS = {"closed_ingest": ClosedIngest, "open_read": OpenRead}
 
 
+def load_module(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def loop_class(kind: str, cells: pathlib.Path = HERE) -> type:
+    """The loop kind a traffic file names: a built-in of `LOOPS`, else the
+    one subclass of `Loop` that `loops/<kind>.py` defines."""
+    if kind in LOOPS:
+        return LOOPS[kind]
+    path = cells / "loops" / f"{kind}.py"
+    if not path.is_file():
+        raise SystemExit(f"unknown loop kind {kind!r}: not built in, and no "
+                         f"{path}")
+    mod = load_module(path, f"loop_{kind}")
+    found = [v for v in vars(mod).values()
+             if isinstance(v, type) and issubclass(v, Loop)
+             and v.__module__ == mod.__name__]
+    if len(found) != 1:
+        raise SystemExit(f"{path} defines {len(found)} subclasses of "
+                         "harness.Loop, not one")
+    return found[0]
+
+
+def build_for(config: str, cells: pathlib.Path = HERE):
+    """`build` of `deploy/<config>.py` where the file exists, else
+    `build_service`."""
+    path = cells / "deploy" / f"{config}.py"
+    if not path.is_file():
+        return build_service
+    return load_module(path, f"deploy_{config}").build
+
+
 def corpus_streams(rng, s: dict, tenants: int):
     """The corpus's event stream (2 * corpus_tokens events), and each
     tenant's copy: rotated by an even offset of its own (so unigram-bigram
@@ -485,12 +547,9 @@ def run_window(loop: Loop, seconds: float) -> None:
 # a run
 # --------------------------------------------------------------------------
 
-def metric_reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+def metric_reader(name: str, cells: pathlib.Path = HERE):
+    return load_module(cells / "metrics" / f"{name}.py",
+                       f"metric_{name}").read
 
 
 def cell_metrics(bench: dict, cell: str, kind: str) -> list[dict]:
@@ -502,30 +561,32 @@ def run_cell(bench: dict, cell: str, seed: int, seconds: float, trace: bool,
              *, root: pathlib.Path, t_start: float, require_tpu: bool = True,
              cfg: dict | None = None, trf: dict | None = None,
              limits: dict | None = None, peaks: dict | None = None,
-             control: bool = False) -> dict:
+             control: bool = False, cells: pathlib.Path = HERE) -> dict:
     """Run one cell and return its result line (a dict).  A traced run
     leaves its trace and the harness's counts (`ctx.json`) in
     `<checkout>/.bench_trace/<cell>/` until the next traced run."""
     wl = next(w for w in bench["workloads"] if w["name"] == cell)
-    cfg = cfg or load_json(HERE / "configs" / f"{wl['config']}.json")
-    trf = dict(trf or load_json(HERE / "traffic" / f"{wl['traffic']}.json"))
-    limits = limits or load_json(HERE / "limits" / f"{cell}.json")
+    cfg = cfg or load_json(cells / "configs" / f"{wl['config']}.json")
+    trf = dict(trf or load_json(cells / "traffic" / f"{wl['traffic']}.json"))
+    loop_cls = loop_class(trf["loop"], cells)
+    build = build_for(wl["config"], cells)
+    limits = limits or load_json(cells / "limits" / f"{cell}.json")
     cache = setup_jax(root)
     log(f"compile cache: {cache}")
     devs = devices(require_tpu, int(wl["chips"]))
     import jax
     from repro.kernels import ops
     if peaks is None:
-        table = load_json(HERE / "peaks.json")
+        table = load_json(cells / "peaks.json")
         if devs[0].device_kind not in table:
             raise SystemExit(
                 f"no peaks for device kind {devs[0].device_kind!r}")
         peaks = table[devs[0].device_kind]
     compiles = CompileCounter()
 
-    svc, names = build_service(
-        cfg, seed, counter=cfg["control_counter"] if control else None)
-    loop = LOOPS[trf["loop"]](svc, names, cfg, trf, seed)
+    svc, names = build(cfg, seed,
+                       cfg["control_counter"] if control else None)
+    loop = loop_cls(svc, names, cfg, trf, seed)
     loop.setup()
     loop.warm()
     loop.reset_record()
@@ -562,7 +623,8 @@ def run_cell(bench: dict, cell: str, seed: int, seconds: float, trace: bool,
                 jax.profiler.stop_trace()
             loop.annotate = False
             loop.epochlog.recording = False
-            ctx = {"cell": cell, "enqueue_s": loop.rec["enqueue_s"],
+            ctx = {"cell": cell, "chips": int(wl["chips"]),
+                   "enqueue_s": loop.rec["enqueue_s"],
                    "enqueue_events": loop.rec["enqueue_events"],
                    "lag_s": loop.rec["lag_s"], "lat_s": loop.rec["lat_s"],
                    **loop.least_bytes()}
@@ -591,7 +653,7 @@ def run_cell(bench: dict, cell: str, seed: int, seconds: float, trace: bool,
             json.dump(ctx, f)
         tr = tracefile.load(tracefile.find_xplane(trace_dir), ctx, peaks)
         for m in cell_metrics(bench, cell, "per_layer"):
-            v = metric_reader(m["name"])(tr)
+            v = metric_reader(m["name"], cells)(tr)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         device["busy_s"] = tr.busy_s()

@@ -46,14 +46,25 @@ def relative_errors(est: np.ndarray, exact: np.ndarray) -> np.ndarray:
     return np.abs(np.asarray(est, np.float64) - exact) / exact
 
 
+def trimmed_mean(e: np.ndarray) -> float:
+    """Mean of all but the 0.1% largest of `e` (at least one kept)."""
+    return float(np.sort(e)[:max(1, int(e.size * 0.999))].mean())
+
+
 def group_errors(groups) -> dict:
     """`are`: mean relative error over every compared answer; `worst_are`:
-    the worst group's (one read, one step or one tenant) mean.  A group whose
-    answers were altered shows in the second even when it is one of many."""
+    the worst group's (one read, one step or one tenant) mean;
+    `worst_median_re`: the worst group's median; `worst_trimmed_are`: the
+    worst group's mean over all but its 0.1% largest errors.  A group
+    whose answers were altered shows in the last three even when it is one
+    of many; the last two are the ones that a single key colliding with
+    heavy keys in every row cannot move."""
     errs = [relative_errors(e, x) for e, x in groups]
     if not errs:
         raise ValueError("nothing was compared")
     allv = np.concatenate(errs)
     return {"are": float(allv.mean()),
             "worst_are": float(max(e.mean() for e in errs)),
+            "worst_median_re": float(max(np.median(e) for e in errs)),
+            "worst_trimmed_are": max(trimmed_mean(e) for e in errs),
             "compared": int(allv.size)}

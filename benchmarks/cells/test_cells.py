@@ -6,8 +6,9 @@ pytest configuration collects only `tests/`):
   * the trace reduction, on synthetic intervals and on traces recorded on a
     v5e (`testdata/`), down to every per-layer metric;
   * the least-byte counts (distinct keys are counted per tenant);
-  * every cell end to end through `harness.run_cell` at a tiny size, sound
-    (correct) and with its control counter (not correct);
+  * every cell end to end through `harness.run_cell` at the tiny size of
+    the `cpu_cut` its configuration and traffic files hold, sound (correct)
+    and with its control counter (not correct);
   * the timed path broken underneath, once per fault the cells can have:
     a flush that leaves the tables unchanged, half of each batch dropped, one
     read's answers altered where they are produced.  `correct` comes out
@@ -35,6 +36,7 @@ sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(ROOT / "src"))
 
 import harness  # noqa: E402
+import reference  # noqa: E402
 import tracefile  # noqa: E402
 
 BENCH = harness.load_json(ROOT / "BENCHMARK.json")
@@ -42,27 +44,37 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 PEAKS = harness.load_json(HERE / "peaks.json")["TPU v5 lite"]
 
 
-def tiny(cell: str):
-    """The cell's own configuration and traffic, cut to a CPU-sized run."""
-    wl = next(w for w in BENCH["workloads"] if w["name"] == cell)
-    cfg = harness.load_json(HERE / "configs" / f"{wl['config']}.json")
-    trf = harness.load_json(HERE / "traffic" / f"{wl['traffic']}.json")
-    for p in cfg["planes"]:
-        p["width"] = 1 << 16
-        p["tenants"] = 4
-    cfg["stream"]["corpus_tokens"] = 1 << 13
-    cfg["queue_capacity"] = 1024
-    trf.update({"events_per_call": 512, "prefill_call": 1024,
-                "read_bigrams": 64, "pool_reads": 32, "rate_per_s": 50})
-    return cfg, trf
+def cut(d: dict, over: dict) -> dict:
+    """`d` with a `cpu_cut`'s overrides: an object under a key merges into
+    the object there, or into each object of the list there (every plane)."""
+    for k, v in over.items():
+        if isinstance(v, dict) and isinstance(d.get(k), list):
+            for item in d[k]:
+                cut(item, v)
+        elif isinstance(v, dict) and isinstance(d.get(k), dict):
+            cut(d[k], v)
+        else:
+            d[k] = v
+    return d
+
+
+def tiny(cell: str, bench: dict = BENCH, cells: pathlib.Path = HERE):
+    """The cell's own configuration and traffic, cut to a CPU-sized run by
+    the `cpu_cut` each file holds."""
+    wl = next(w for w in bench["workloads"] if w["name"] == cell)
+    cfg = harness.load_json(cells / "configs" / f"{wl['config']}.json")
+    trf = harness.load_json(cells / "traffic" / f"{wl['traffic']}.json")
+    return cut(cfg, cfg["cpu_cut"]), cut(trf, trf["cpu_cut"])
 
 
 def run_tiny(cell: str, seed: int = 2 ** 31 + 77, control: bool = False,
-             trace: bool = False) -> dict:
-    cfg, trf = tiny(cell)
-    return harness.run_cell(BENCH, cell, seed, 1.0, trace, root=ROOT,
+             trace: bool = False, bench: dict = BENCH,
+             cells: pathlib.Path = HERE, root: pathlib.Path = ROOT) -> dict:
+    cfg, trf = tiny(cell, bench, cells)
+    return harness.run_cell(bench, cell, seed, 1.0, trace, root=root,
                             t_start=time.perf_counter(), require_tpu=False,
-                            cfg=cfg, trf=trf, peaks=PEAKS, control=control)
+                            cfg=cfg, trf=trf, peaks=PEAKS, control=control,
+                            cells=cells)
 
 
 # ---- trace reduction ----
@@ -91,7 +103,8 @@ def _synthetic() -> tracefile.Trace:
            "reads": [{"probes": 30, "distinct": 20, "cell_bytes": 2,
                       "depth": 2}],
            "enqueue_s": 2e-6, "enqueue_events": 1000,
-           "lag_s": [0.001] * 19 + [0.003]}
+           "lag_s": [0.001] * 19 + [0.003],
+           "lat_s": [0.002] * 19 + [0.010]}
     return tracefile.Trace(ops, spans, ctx, {"hbm_bytes_per_s": 1e9})
 
 
@@ -134,6 +147,8 @@ def test_readers_on_synthetic():
     assert r["gen_lag_p95_ms"] == pytest.approx(
         np.percentile([0.001] * 19 + [0.003], 95) * 1e3)
     assert r["enqueue_host_ns_per_event"] == pytest.approx(2.0)
+    assert r["read_latency_p95_ms"] == pytest.approx(
+        np.percentile([0.002] * 19 + [0.010], 95) * 1e3)
 
 
 def test_readers_silent_without_their_input():
@@ -163,6 +178,24 @@ def test_recorded_trace(path, tmp_path):
             assert 0 < v <= 100, (m["name"], v)
     bd = tr.breakdown()
     assert bd["device_ops"] and bd["idle_gaps"]
+
+
+# ---- the numbers compared ----
+
+def test_one_colliding_key_moves_only_the_untrimmed_mean():
+    """A group of 5,000 exact answers but one count-1 key read as 335 (a
+    key colliding with heavy keys in both rows) moves the group's mean past
+    0.06, and neither its median nor its trimmed mean; a group altered x1.5
+    moves all three to 0.5."""
+    exact = np.ones(5000)
+    one_key = exact.copy()
+    one_key[7] = 335.0
+    got = reference.group_errors([(one_key, exact), (exact, exact)])
+    assert got["worst_are"] > 0.06
+    assert got["worst_median_re"] == 0 and got["worst_trimmed_are"] == 0
+    got = reference.group_errors([(exact * 1.5, exact), (exact, exact)])
+    for k in ("worst_are", "worst_median_re", "worst_trimmed_are"):
+        assert got[k] == pytest.approx(0.5), k
 
 
 # ---- least bytes: distinct keys per tenant ----

@@ -12,7 +12,9 @@ A traced run writes one `.xplane.pb`.  From it this keeps:
   * `peaks`: the device's row of `peaks.json`.
 
 Busy time is the union of device-op intervals inside the window, averaged
-over the devices that ran any op; idle share is 1 minus busy over window.
+over the cell's chips (`ctx["chips"]`, so a chip that ran nothing counts
+as idle; without it, over the devices that ran any op); idle share is 1
+minus busy over window.
 """
 from __future__ import annotations
 
@@ -87,10 +89,12 @@ class Trace:
         return iv[iv[:, 1] > iv[:, 0]]
 
     def busy_s(self) -> float:
-        """Union of device-op time in the window, averaged over devices."""
-        if not self.devices:
+        """Union of device-op time in the window, averaged over the cell's
+        chips (or over more devices, where more ran ops)."""
+        chips = max(int(self.ctx.get("chips", 0)), len(self.devices))
+        if not chips:
             return 0.0
-        return self._union_s(self.ops) / len(self.devices)
+        return self._union_s(self.ops) / chips
 
     def program_time_s(self, patterns) -> float:
         """Device time of the ops of programs whose name matches."""
