@@ -1,7 +1,7 @@
 """Smoke run of the counting service's serve path on a TPU.
 
     python chip_smoke.py              # one chip: CountService serve path
-    python chip_smoke.py --chips 4    # four chips: key-routed counting only
+    python chip_smoke.py --chips 4    # four chips: the key-routed plane only
 
 One chip: drives `repro.launch.serve_counts.main` at a deployment state
 size — 17 tenants x (4 x 2^22) CMLS16 tables (32 MiB each) in the wide
@@ -13,10 +13,13 @@ no CMS32 estimate falls below its key's exact count on the same seeded
 stream.  The snapshot round-trip inside `main` raises if it changes an
 answer.
 
-Four chips: key-routed `sharded.routed_update` / `routed_query` /
-`routed_topk` under `shard_map` on a 4-device mesh with per-shard CMS32
-tables at w=2^22, d=4, checked against exact counts and against one
-device's `sk.update_batched` over the whole stream.
+Four chips: counting through `CountService`'s key-routed plane
+(`CountService(mesh=...)`) on a 4-device mesh, per-shard CMS32 tables at
+w=2^22, d=4: `enqueue_many`, queue-pressure and explicit flush epochs
+(each one `_routed_epoch` program: local dedup, `all_to_all` to each
+key's owner, in-place update), `query` and `topk`, checked against exact
+counts, against one device's plain plane over the same stream, and
+against the routing counters (every event routed, none dropped).
 
 Earlier lines report the engine each op took, compile seconds, the wall
 time of each phase, `peak_bytes_in_use` and ARE by decile.  The last line
@@ -40,7 +43,7 @@ SERVE_ARGS = ["--tenants", "16", "--width", "4194304", "--depth", "4",
               "--batch", "4096", "--batches", "20"]
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-# four-chip phase: per-shard CMS32 tables, STEPS batches of BATCH keys per
+# four-chip phase: per-shard CMS32 tables, STEPS enqueues of BATCH keys per
 # shard drawn from a Zipf stream over KEY_SPACE ids, top-K heavy hitters
 ROUTED_WIDTH = 1 << 22
 ROUTED_DEPTH = 4
@@ -110,64 +113,24 @@ def serve_phase() -> None:
         log(f"ARE by decile {tenant}: {json.dumps(run.ares[tenant])}")
 
 
-def routed_programs(mesh, spec):
-    """Jitted key-routed update / query / top-k over `mesh`'s "data" axis."""
-    import jax
-    from jax.sharding import PartitionSpec as P
-
-    from repro.core import sharded, topk
-    from repro.core import sketch as sk
-
-    data = P("data")
-
-    def upd(table, keys, rng):
-        s = sk.Sketch(table=table[0], spec=spec)
-        return sharded.routed_update(s, keys[0], rng[0], "data",
-                                     capacity=keys.shape[1]).table[None]
-
-    def qry(table, keys):
-        s = sk.Sketch(table=table[0], spec=spec)
-        return sharded.routed_query(s, keys[0], "data",
-                                    capacity=keys.shape[1])[None]
-
-    def top(table, cand):
-        # each shard tracks the candidates its routing partition owns
-        s = sk.Sketch(table=table[0], spec=spec)
-        n = jax.lax.axis_size("data")
-        mine = sharded.route_of(cand, n) == jax.lax.axis_index("data")
-        tr = sharded.routed_topk(
-            topk.refresh(topk.init(TOP_K), s, cand, mine), "data")
-        return tr.keys[None], tr.estimates[None], tr.filled[None]
-
-    update = jax.jit(jax.shard_map(upd, mesh=mesh, in_specs=(data,) * 3,
-                                   out_specs=data), donate_argnums=0)
-    query = jax.jit(jax.shard_map(qry, mesh=mesh, in_specs=(data, data),
-                                  out_specs=data))
-    heavy = jax.jit(jax.shard_map(top, mesh=mesh, in_specs=(data, P()),
-                                  out_specs=(data, data, data)))
-    return update, query, heavy
-
-
 def routed_phase(n_chips: int) -> None:
-    """Key-routed counting over `n_chips` devices vs exact counts and one
-    device's one-shot batched update over the same stream."""
+    """Key-routed counting through `CountService`'s routed plane over
+    `n_chips` devices, against exact counts and against one device's plain
+    plane over the same stream."""
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, Mesh
 
     from repro.core import CMS32, SketchSpec
-    from repro.core import sketch as sk
-    from repro.launch.mesh import make_mesh
+    from repro.stream import CountService
 
     n = len(jax.devices())
     check(n == n_chips, f"need {n_chips} devices, JAX sees {n}")
-    mesh = make_mesh((n,), ("data",))
-    shard = NamedSharding(mesh, P("data"))
+    mesh = Mesh(np.asarray(jax.devices()), ("keys",),
+                axis_types=(AxisType.Auto,))
     spec = SketchSpec(width=ROUTED_WIDTH, depth=ROUTED_DEPTH, counter=CMS32)
-    update, query, heavy = routed_programs(mesh, spec)
 
     rng = np.random.default_rng(SEED)
-    stream = (rng.zipf(1.2, (STEPS, n, BATCH)) % KEY_SPACE).astype(np.uint32)
+    stream = (rng.zipf(1.2, (STEPS, n * BATCH)) % KEY_SPACE).astype(np.uint32)
     uniq, exact = np.unique(stream, return_counts=True)
     order = np.argsort(-exact, kind="stable")
     check(exact[order[TOP_K - 1]] > exact[order[TOP_K]],
@@ -175,65 +138,67 @@ def routed_phase(n_chips: int) -> None:
     true_top = set(uniq[order[:TOP_K]].tolist())
     phases = {}
 
-    t0 = time.perf_counter()
-    tables = jax.jit(lambda: jnp.zeros((n, spec.depth, spec.storage_width),
-                                       spec.storage_dtype),
-                     out_shardings=shard)()
-    key = jax.random.PRNGKey(SEED)
-    for step in range(STEPS):
-        rngs = jax.device_put(jax.random.split(jax.random.fold_in(key, step),
-                                               n), shard)
-        tables = update(tables, jax.device_put(stream[step], shard), rngs)
-    jax.block_until_ready(tables)
-    phases["routed_update"] = time.perf_counter() - t0
+    def count(svc) -> None:
+        svc.add_tenant("keys", spec=spec)
+        for step in range(STEPS):
+            svc.enqueue_many({"keys": stream[step]})
+        svc.flush()
+        jax.block_until_ready(svc.planes[0].tables)
 
-    # probes split evenly over the shards; capacity = the per-shard slice,
-    # so no destination can overflow and no key is dropped
-    m = -(-uniq.size // n)
-    probes = np.resize(uniq, n * m).reshape(n, m)
     t0 = time.perf_counter()
-    routed = np.asarray(query(tables, jax.device_put(probes, shard)))
-    routed = routed.reshape(-1)[:uniq.size]
+    routed = CountService(queue_capacity=2 * n * BATCH, seed=SEED,
+                          track_top=TOP_K, mesh=mesh)
+    count(routed)
+    phases["routed_ingest"] = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    est = np.asarray(routed.query("keys", uniq))
     phases["routed_query"] = time.perf_counter() - t0
-    check(not (routed == -1.0).any(), "routed_query dropped keys")
 
     t0 = time.perf_counter()
-    top_keys, top_est, filled = (np.asarray(x) for x in heavy(
-        tables, jnp.asarray(uniq)))
+    top_keys, top_est = routed.topk("keys")
     phases["routed_topk"] = time.perf_counter() - t0
 
     t0 = time.perf_counter()
-    single = jax.jit(sk.update_batched)(
-        sk.init(spec), jnp.asarray(stream.reshape(-1)), key)
-    single_est = np.asarray(jax.jit(sk.query)(single, jnp.asarray(uniq)))
+    one = CountService(queue_capacity=2 * n * BATCH, seed=SEED,
+                       track_top=TOP_K)
+    count(one)
+    single_est = np.asarray(one.query("keys", uniq))
     phases["single_device_reference"] = time.perf_counter() - t0
 
-    over_routed = routed - exact
+    m = routed.metrics
+    routed_keys = m.counter("routed_keys", plane="p0").value
+    dropped = m.counter("routed_dropped", plane="p0").value
+    skew = m.gauge("routed_shard_keys_max", plane="p0").value
+    over_routed = est - exact
     over_single = single_est - exact
-    log(f"{STEPS} steps x {n} shards x {BATCH} keys, {uniq.size} distinct; "
-        f"routed over-count sum {float(over_routed.sum())} max "
-        f"{float(over_routed.max())}, single-device sum "
-        f"{float(over_single.sum())} max {float(over_single.max())}")
+    log(f"{STEPS} enqueues of {n * BATCH} keys over {n} shards, "
+        f"{uniq.size} distinct; routed over-count sum "
+        f"{float(over_routed.sum())} max {float(over_routed.max())}, "
+        f"single-device sum {float(over_single.sum())} max "
+        f"{float(over_single.max())}")
     log(f"ARE routed {float(np.mean(np.abs(over_routed) / exact))}, "
-        f"single-device {float(np.mean(np.abs(over_single) / exact))}")
+        f"single-device {float(np.mean(np.abs(over_single) / exact))}; "
+        f"routed_keys {routed_keys}, routed_dropped {dropped}, busiest "
+        f"shard over the mean {skew}")
+    check(routed_keys == stream.size, "routed_keys differs from the events")
+    check(dropped == 0, f"the exchange dropped {dropped} events")
     check((over_routed >= 0).all(), "a routed estimate is below its exact "
                                     "count")
     check((over_single >= 0).all(), "a single-device estimate is below its "
                                     "exact count")
     check(over_routed.sum() <= over_single.sum(),
           "routed tables over-count more than one device's table")
-    check((top_keys == top_keys[0:1]).all(), "shards disagree on the top-k")
-    check(filled.all(), "routed top-k has unfilled slots")
-    check(set(top_keys[0].tolist()) == true_top,
+    check(set(top_keys.tolist()) == true_top,
           "routed top-k differs from the exact heavy hitters")
     single_top = set(uniq[np.argsort(-single_est, kind="stable")[:TOP_K]]
                      .tolist())
     check(single_top == true_top,
           "single-device top-k differs from the exact heavy hitters")
-    got = dict(zip(top_keys[0].tolist(), top_est[0].tolist()))
     pos = {k: i for i, k in enumerate(uniq.tolist())}
-    check(all(got[k] == routed[pos[k]] for k in true_top),
-          "routed top-k estimates differ from routed_query answers")
+    check(all(e == est[pos[k]] for k, e in zip(top_keys.tolist(),
+                                                top_est.tolist())),
+          "routed top-k estimates differ from the query answers")
     log(f"routed top-{TOP_K} equals the exact heavy hitters: "
         f"{sorted(true_top)}")
     log(f"phase wall seconds: {json.dumps(phases)}")
@@ -242,7 +207,7 @@ def routed_phase(n_chips: int) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4 runs only the key-routed four-chip phase")
+                    help="4 runs only the key-routed plane's phase")
     args = ap.parse_args(argv)
     result = {"ok": False}
     try:

@@ -30,10 +30,11 @@ import numpy as np
 from repro.core import admission
 from repro.core import sketch as sk
 from repro.core import topk
-from repro.core.hashing import mix32
+from repro.core.hashing import host_row_seeds, mix32, row_hashes
 
 SENTINEL = jnp.uint32(0xFFFF_FFFF)
 _ROUTE_SALT = jnp.uint32(0x60D5)
+_LANE_SALT = 0x1A4E5
 
 
 # --------------------------------------------------------------------------
@@ -179,6 +180,115 @@ def routed_update(local: sk.Sketch, keys: jnp.ndarray, rng: jax.Array,
     valid = flat != SENTINEL
     # sentinel keys carry weight 0 -> no-op inside the batched update
     return sk.update_batched(local, flat, rng, weights=valid.astype(jnp.float32))
+
+
+def shard_seeds(seed: int, n_shards: int) -> tuple:
+    """Seeds of the uniform lanes of a key-routed plane's shards: shard s
+    draws epoch f's uniforms from the raw key (seeds[s], f), so no two
+    shards draw the same randoms (host ints, like `host_row_seeds`)."""
+    return host_row_seeds(seed ^ _LANE_SALT, n_shards)
+
+
+def route_rows(keys: jnp.ndarray, weights: jnp.ndarray, n_shards: int):
+    """Send buffers of a key-routed exchange of stacked rows.
+
+    keys/weights (R, N): each row's (key, weight) entries; those with
+    weight > 0 are packed by owner (`route_of`) into (n_shards, R, N)
+    buffers, padding (key 0, weight 0) after them.  Each owner has N slots
+    per row, as many as the row has entries, so no owner's share can
+    overflow and no entry is dropped.  Returns (keys, weights, dropped),
+    `dropped` the weight of the entries that found no slot (counted all
+    the same)."""
+    r, n = keys.shape
+    live = weights > 0
+    dest = jnp.where(live, route_of(keys, n_shards), n_shards)
+    order = jnp.argsort(dest, axis=1, stable=True)
+    to = jnp.take_along_axis(dest, order, axis=1)
+    counts = jax.vmap(lambda d: jnp.bincount(d, length=n_shards + 1))(dest)
+    start = jnp.cumsum(counts, axis=1) - counts
+    rank = jnp.arange(n)[None, :] - jnp.take_along_axis(start, to, axis=1)
+    keep = (to < n_shards) & (rank < n)
+    slot = jnp.where(keep, to * n + rank, n_shards * n)   # past the end: off
+    at = jnp.arange(r)[:, None]
+    buf_k = jnp.zeros((r, n_shards * n), jnp.uint32).at[at, slot].set(
+        jnp.take_along_axis(keys, order, axis=1).astype(jnp.uint32),
+        mode="drop")
+    w_sorted = jnp.take_along_axis(weights, order, axis=1)
+    buf_w = jnp.zeros((r, n_shards * n), weights.dtype).at[at, slot].set(
+        w_sorted, mode="drop")
+    dropped = jnp.where((to < n_shards) & ~keep, w_sorted, 0).sum()
+    split = lambda b: b.reshape(r, n_shards, n).transpose(1, 0, 2)  # noqa: E731
+    return split(buf_k), split(buf_w), dropped
+
+
+def routed_exchange(keys: jnp.ndarray, weights: jnp.ndarray,
+                    axis_name: str):
+    """Route each row's (key, weight) entries to their owners (call inside
+    shard_map over `axis_name`): pack them by `route_of` (`route_rows`)
+    and swap the buffers with one `all_to_all` each.  Returns what this
+    shard owns, (R, n_shards * N) keys and weights (the block from shard j
+    at columns j*N..), and the weight dropped at this source (0)."""
+    n_shards = jax.lax.axis_size(axis_name)
+    send_k, send_w, dropped = route_rows(keys, weights, n_shards)
+    recv_k = jax.lax.all_to_all(send_k, axis_name, 0, 0)
+    recv_w = jax.lax.all_to_all(send_w, axis_name, 0, 0)
+    r = keys.shape[0]
+    join = lambda b: b.transpose(1, 0, 2).reshape(r, -1)  # noqa: E731
+    return join(recv_k), join(recv_w), dropped
+
+
+def routed_epoch(tables, tracker, queue, fill, rows, rng, *, spec,
+                 cols: int, axis_name: str):
+    """One flush epoch of a key-routed plane, on one shard (call inside
+    shard_map over `axis_name`; every argument but `rows` is this shard's
+    block, with a leading axis of 1).
+
+    tables (1, T, d, w) this shard's key partition; tracker a (1, T, K)
+    `TopK` of the keys it owns (K may be 0); queue (1, T, capw) its column
+    share of the ring, fill (1, R) the rows' live slots in it; rows (R,)
+    the active rows; rng (1, 2) this shard's uniform key.  In order: dedup
+    the local share, route each key to its owner with its weight
+    (`routed_exchange`), land the conservative update and re-score the
+    candidates on the owned stack (`ops.update_score_rows`, in place), and
+    re-select the heaps.  Returns (tables, tracker, stats), stats (1, 2):
+    the weight this shard received and the weight dropped at it.
+    """
+    from repro.kernels import ops
+    live = jnp.arange(cols)[None, :] < fill[0][:, None]
+    keys, mult = jax.vmap(sk.dedup_weighted)(queue[0][rows, :cols],
+                                             live.astype(jnp.float32))
+    recv_k, recv_w, dropped = routed_exchange(keys, mult, axis_name)
+    heap = topk.TopK(keys=tracker.keys[0][rows],
+                     estimates=tracker.estimates[0][rows],
+                     filled=tracker.filled[0][rows])
+    cand, valid = topk.candidates(heap, recv_k, recv_w > 0)
+    stack, est = ops.update_score_rows(tables[0], spec, recv_k, rng[0], rows,
+                                       cand, weights=recv_w, engine="inplace")
+    k = heap.keys.shape[1]
+    if k:
+        new = topk.reselect(cand, valid, est, k)
+        tracker = topk.TopK(
+            keys=tracker.keys.at[0, rows].set(new.keys),
+            estimates=tracker.estimates.at[0, rows].set(new.estimates),
+            filled=tracker.filled.at[0, rows].set(new.filled))
+    stats = jnp.stack([recv_w.sum(), dropped.astype(recv_w.dtype)])
+    return stack[None], tracker, stats[None]
+
+
+def routed_query_rows(tables, rows, probes, *, spec, axis_name: str):
+    """(R, P) estimates of `probes` (R, P) in rows `rows` (R,) of a
+    key-routed plane (call inside shard_map over `axis_name`, tables this
+    shard's (1, T, d, w) block, rows and probes replicated): each probe is
+    read by its owner (`route_of`) from its own stack, the other shards
+    add 0, and a `psum` gives every shard the answers."""
+    n_shards = jax.lax.axis_size(axis_name)
+    mine = route_of(probes, n_shards) == jax.lax.axis_index(axis_name)
+    seeds = jnp.asarray(host_row_seeds(spec.seed, spec.depth), jnp.uint32)
+    cols = jax.vmap(lambda k: row_hashes(k, seeds, spec.width))(probes)
+    cells = tables[0][rows[:, None, None],
+                      jnp.arange(spec.depth)[None, :, None], cols]
+    est = spec.counter.decode(cells.min(axis=1))
+    return jax.lax.psum(jnp.where(mine, est, 0.0), axis_name)
 
 
 def _route_estimates_back(est: jnp.ndarray, recv_keys: jnp.ndarray,

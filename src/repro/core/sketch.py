@@ -70,6 +70,11 @@ class SketchSpec:
     packed: bool = False
 
     def __post_init__(self):
+        if self.width >= 2 ** 31:
+            raise ValueError(
+                f"width {self.width} must be below 2^31: row_hashes casts "
+                "columns to int32, so a wider row would index the wrong "
+                "cells")
         if self.packed and self.width % self.counter.cells_per_lane:
             raise ValueError(
                 f"packed width {self.width} must be a multiple of "

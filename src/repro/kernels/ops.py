@@ -114,6 +114,12 @@ def _launch(name: str, engine: str) -> None:
         scope.engines[name, engine] += 1
 
 
+def note_dispatch(name: str, engine: str = "xla") -> None:
+    """Tally a dispatch issued outside this module's wrappers (the
+    key-routed plane's `shard_map` programs) into the active scopes."""
+    _launch(name, engine)
+
+
 def launch_counts() -> dict[str, int]:
     """Snapshot of the DEFAULT scope's {op: dispatches} since its last
     reset (prefer `audit_scope()` for isolated windows)."""
@@ -547,6 +553,16 @@ def _update_score_rows_xla_jit(tables, keys, weights, rng, rows, urows, cand,
                                      cpl=spec.cells_per_lane)
 
 
+def _update_score_rows_inplace(tables, keys, weights, rng, rows, urows,
+                               cand, *, spec, total):
+    sorted_keys, mult = jax.vmap(sk.dedup_weighted)(keys, weights)
+    uniforms = _parity_uniforms(rng, keys.shape[1], total, urows)
+    return ref.update_score_rows_inplace_ref(tables, sorted_keys, mult,
+                                             uniforms, rows, cand,
+                                             _row_seeds_array(spec),
+                                             spec.counter, CHUNK)
+
+
 def update_score_rows(tables: jnp.ndarray, spec: sk.SketchSpec,
                       keys: jnp.ndarray, rng: jax.Array, rows,
                       cand: jnp.ndarray,
@@ -574,10 +590,27 @@ def update_score_rows(tables: jnp.ndarray, spec: sk.SketchSpec,
     engine: "kernel" forces the Pallas path, "xla" the jitted reference
     (`ref.update_score_rows_ref` — chunk-sequential, bit-identical), and
     "auto" takes the XLA reference on every platform (see the module
-    docstring).  Returns (new_tables, estimates).
+    docstring).  "inplace" is the key-routed epoch's engine, called inside
+    its `shard_map` trace on one shard's stack (`rows` traced too), so no
+    dispatch of its own: the same dedup, uniforms and chunk-sequential
+    update, through `ref.update_score_rows_inplace_ref`, which writes the
+    rows' cells where they lie instead of gathering the rows out and
+    scattering them back, so a donated stack too large to copy is updated
+    in place; bit-identical to "xla" (unpacked stacks only).  Returns
+    (new_tables, estimates).
     """
-    if engine not in ("auto", "kernel", "xla"):
+    if engine not in ("auto", "kernel", "xla", "inplace"):
         raise ValueError(f"unknown update_score engine {engine!r}")
+    if engine == "inplace":
+        if spec.packed:
+            raise ValueError("the in-place engine updates unpacked stacks")
+        total, urows = ((tables.shape[0], rows) if uniform_rows is None
+                        else uniform_rows)
+        if weights is None:
+            weights = jnp.ones(keys.shape, jnp.float32)
+        return _update_score_rows_inplace(tables, keys, weights, rng, rows,
+                                          urows, cand, spec=spec,
+                                          total=int(total))
     rows = np.asarray(rows, np.int32)
     if uniform_rows is None:
         total, urows = tables.shape[0], rows
@@ -737,6 +770,11 @@ def _queue_append_rows_xla(queue, keys, meta, *, aligned):
     cols = jnp.arange(capw, dtype=jnp.int32)[None, :]
     valid = (cols >= fill[:, None]) & (cols < (fill + count)[:, None])
     return queue.at[rows].set(jnp.where(valid, buf, queue[rows]))
+
+
+# the row append as a building block of a traced program (one shard's ring
+# inside the key-routed plane's shard_map append)
+queue_append_local = _queue_append_rows_xla
 
 
 @functools.partial(jax.jit, static_argnames=("aligned",),

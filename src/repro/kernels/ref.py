@@ -17,6 +17,8 @@ queue-append pattern).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -54,13 +56,28 @@ def update_ref(table: jnp.ndarray, keys: jnp.ndarray, mult: jnp.ndarray,
     d, w = table.shape
     cols = row_hashes(keys, row_seeds, w)                 # (d, N)
     rows = jnp.arange(d)[:, None]
-    cur = table[rows, cols]
-    cmin = cur.min(axis=0)
+    table = conservative_pass(table, (rows, cols), mult, uniforms, counter)
+    return pack_table(table, 32 // cpl) if cpl > 1 else table
+
+
+def conservative_pass(table: jnp.ndarray, cells: tuple, mult: jnp.ndarray,
+                      uniforms: jnp.ndarray, counter: CounterSpec
+                      ) -> jnp.ndarray:
+    """One pass of the conservative update over the cells `table[cells]`.
+
+    `cells` indexes (..., d, N) cells: each key's d cells on the
+    second-to-last axis.  Every key reads its cells' minimum, moves `mult`
+    units from it (the n-fold Morris step, `uniforms` drawing the
+    rounding), and writes the new state to every one of its cells by max.
+    mult == 0 writes 0, a no-op.  The core of `update_ref` and of
+    `update_score_rows_inplace_ref`.
+    """
+    cur = table[cells]
+    cmin = cur.min(axis=-2)
     new_state = counter.nfold(cmin, mult, uniforms)
     write = jnp.where(mult > 0, new_state, jnp.zeros_like(new_state))
-    table = table.at[rows, cols].max(
-        jnp.broadcast_to(write[None], (d, keys.shape[0])))
-    return pack_table(table, 32 // cpl) if cpl > 1 else table
+    return table.at[cells].max(
+        jnp.broadcast_to(jnp.expand_dims(write, -2), cur.shape))
 
 
 def update_chunked_ref(table: jnp.ndarray, keys: jnp.ndarray,
@@ -119,6 +136,51 @@ def update_score_rows_ref(tables: jnp.ndarray, keys: jnp.ndarray,
     if cpl > 1:
         new_rows = pack_table(new_rows, 32 // cpl)
     return tables.at[rows].set(new_rows), est
+
+
+def update_score_rows_inplace_ref(tables: jnp.ndarray, keys: jnp.ndarray,
+                                  mult: jnp.ndarray, uniforms: jnp.ndarray,
+                                  rows: jnp.ndarray, cand: jnp.ndarray,
+                                  row_seeds: jnp.ndarray,
+                                  counter: CounterSpec, chunk: int):
+    """`update_score_rows_ref` landed in place: each chunk's pass reads and
+    writes the R rows' cells straight in the (T, d, w) stack, so no row is
+    gathered out and scattered back, and a donated stack is updated where
+    it lies.  Bit-identical to it (unpacked tables).
+
+    Chunks run in order, one loop step each, all R rows at once (rows are
+    distinct tables); the steps run only over the chunks from the first to
+    the last that carries an event (mult > 0 in some row), since a chunk
+    of no-ops changes nothing.  Returns (tables, float32 (R, M) estimates
+    of `cand` read from the updated rows).
+    """
+    r, n = keys.shape
+    pad = -n % chunk
+    keys = jnp.pad(keys, ((0, 0), (0, pad)))
+    mult = jnp.pad(mult, ((0, 0), (0, pad)))
+    uniforms = jnp.pad(uniforms, ((0, 0), (0, pad)), constant_values=1.0)
+    n += pad
+    d, w = tables.shape[1:]
+    at_row = rows[:, None, None]
+    at_depth = jnp.arange(d)[None, :, None]
+    hashes = jax.vmap(lambda k: row_hashes(k, row_seeds, w))
+
+    pos = jnp.arange(n)
+    live = (mult > 0).any(axis=0)
+    first = jnp.min(jnp.where(live, pos, n)) // chunk
+    last = (jnp.max(jnp.where(live, pos, -1)) + chunk) // chunk
+
+    def step(i, tables):
+        sl = functools.partial(jax.lax.dynamic_slice_in_dim,
+                               start_index=i * chunk, slice_size=chunk,
+                               axis=1)
+        cells = (at_row, at_depth, hashes(sl(keys)))
+        return conservative_pass(tables, cells, sl(mult), sl(uniforms),
+                                 counter)
+
+    tables = jax.lax.fori_loop(first, last, step, tables)
+    cells = tables[at_row, at_depth, hashes(cand)]
+    return tables, counter.decode(cells.min(axis=1))
 
 
 def window_query_stacked_ref(tables: jnp.ndarray, keys: jnp.ndarray,

@@ -21,7 +21,8 @@ from repro.stream.window import (DecayedSketch, WindowSpec, WindowedSketch,
                                  window_query_many, window_rotate,
                                  window_update, window_weights,
                                  window_weights_stacked)
-from repro.stream.service import CountService, TenantPlane, WindowPlane
+from repro.stream.service import (CountService, RoutedPlane, TenantPlane,
+                                  WindowPlane)
 from repro.stream.tiering import TierSpec, tier_memory_bytes
 
 __all__ = [
@@ -31,6 +32,6 @@ __all__ = [
     "window_weights_stacked", "interval_epoch", "interval_lag",
     "DecayedSketch", "decay", "decayed_init", "decayed_rotate",
     "decayed_update", "decayed_query",
-    "CountService", "TenantPlane", "WindowPlane",
+    "CountService", "RoutedPlane", "TenantPlane", "WindowPlane",
     "TierSpec", "tier_memory_bytes",
 ]

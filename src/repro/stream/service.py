@@ -62,6 +62,14 @@ via one gather→host copy + one host→device scatter per epoch.  The
 hot-tier flush epoch stays ONE `update_score_rows` dispatch, and
 `query_all`/`topk` answers are bit-identical to an all-resident service.
 
+Construction with `mesh=` (a 1-D `jax.sharding.Mesh`) makes every plain
+plane a `RoutedPlane`: its tables sharded by key over the mesh's axis
+(shard s holds the (T, d, w) stack of the keys `sharded.route_of` gives
+it), its ring sharded by column, and each flush epoch one `shard_map`
+program that routes every key to its owner by `all_to_all` and updates
+the owner's stack in place — the deployment for tables past one chip's
+memory.  Windows, tiers, admission and snapshots are single-chip only.
+
 Queries are read-your-writes: they flush pending events first.  The whole
 service (tables + rings + fill mirrors + RNG lane + stats + trackers +
 admission registry) snapshots and restores via `train/checkpoint`; the
@@ -77,14 +85,17 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import obs
 from repro.core import admission as adm
+from repro.core import sharded
 from repro.core import sketch as sk
 from repro.core import topk
 from repro.core.counters import CounterSpec
@@ -374,7 +385,6 @@ class _TierMixin:
         `sharded.tier_assemble`)."""
         if self.tier is None:
             return self.tables
-        from repro.core import sharded
         return sharded.tier_assemble(self.tables, self.tier.slot_tenant,
                                      self.tier.cold)
 
@@ -1167,6 +1177,270 @@ class WindowPlane(_TierMixin, _TrackerMixin, _TelemetryMixin):
         return jnp.asarray(self.tier.cold[row, cur])
 
 
+class RoutedPlane(_TelemetryMixin):
+    """Tenants sharing one SketchSpec, their tables sharded by key over a
+    1-D mesh (`CountService(mesh=...)`): the key-routed deployment, for
+    tables too large for one chip.
+
+    Shard s of the mesh axis holds the (T, d, w) stack of the keys it owns
+    (`sharded.route_of(key) == s`), so `spec.width` is one shard's width
+    and the plane counts over n * w columns per row.  The queue ring is
+    sharded by column: every append spreads each tenant's batch over the
+    shards so their fills differ by at most one, and each chip holds its
+    share of the pending events.  A flush epoch is ONE jitted `shard_map`
+    program, `_routed_epoch`, with the stack and the heaps donated: every
+    shard dedups its share, routes each key to its owner with its weight
+    (`all_to_all`), lands the conservative update and candidate re-score
+    in place on the stack it owns, and re-selects its heaps.  Reads route
+    each probe to its owner and gather the answers back; `topk` merges the
+    shards' heaps (disjoint key sets).  Every enqueued event is visible to
+    the next read, as on a plain plane.
+
+    The exchange gives every owner as many slots per row as the row has
+    entries, so it cannot overflow; the registry counts what it routes
+    (`routed_keys`, events) and drops (`routed_dropped`, 0 by that sizing)
+    and the load of the busiest shard over the mean
+    (`routed_shard_keys_max`).  The device's counts are read back without
+    blocking, at the next epoch or read.  Windows, tiers, packed storage,
+    admission and snapshots are not supported on a routed plane.
+    """
+
+    tier = None
+
+    def __init__(self, spec: SketchSpec, queue_capacity: int, mesh,
+                 seed: int = 0, track_top: Optional[int] = None,
+                 metrics: Optional[obs.MetricsRegistry] = None,
+                 label: str = "p0"):
+        if spec.packed:
+            raise ValueError("a key-routed plane stores one cell per lane "
+                             "(packed=False): its epoch updates the stack "
+                             "in place")
+        self.spec = spec
+        self.mesh = mesh
+        self.shards = int(mesh.size)
+        axis = mesh.axis_names[0]
+        by_shard = PartitionSpec(axis)
+        self._sharded = NamedSharding(mesh, by_shard)
+        self._replicated = NamedSharding(mesh, PartitionSpec())
+        self.share = -(-int(queue_capacity) // self.shards)
+        self.names: list[str] = []
+        self.track_top = track_top
+        self.rng = _RngLane(seed)
+        self._lanes = np.asarray(sharded.shard_seeds(seed, self.shards),
+                                 np.uint32)
+        self.fill = np.zeros((self.shards, 0), np.int64)
+        self._pending_stats: list = []
+        self._init_telemetry(metrics, label)
+        self._m_routed = self.metrics.counter("routed_keys", plane=label)
+        self._m_dropped = self.metrics.counter("routed_dropped", plane=label)
+        self._g_skew = self.metrics.gauge("routed_shard_keys_max",
+                                          plane=label)
+
+        def shard_map(body, in_specs, out_specs):
+            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs)
+
+        def _routed_epoch(tables, heaps, queue, fill, rows, rng, *, cols):
+            body = functools.partial(sharded.routed_epoch, spec=spec,
+                                     cols=cols, axis_name=axis)
+            return shard_map(body, (by_shard,) * 4 + (PartitionSpec(),
+                                                      by_shard),
+                             (by_shard,) * 3)(tables, heaps, queue, fill,
+                                              rows, rng)
+
+        def _routed_append(queue, keys, meta, *, aligned):
+            def body(q, k, m):
+                return ops.queue_append_local(q[0], k[0], m[0],
+                                              aligned=aligned)[None]
+            return shard_map(body, (by_shard,) * 3, by_shard)(queue, keys,
+                                                              meta)
+
+        def _routed_query(tables, rows, probes):
+            body = functools.partial(sharded.routed_query_rows, spec=spec,
+                                     axis_name=axis)
+            return shard_map(body, (by_shard, PartitionSpec(),
+                                    PartitionSpec()),
+                             PartitionSpec())(tables, rows, probes)
+
+        def _routed_grow(tables, heaps, queue):
+            # one more tenant row in every shard's stack, heaps and ring
+            def grow(a, fill_value=0):
+                pad = jnp.full(a.shape[:1] + (1,) + a.shape[2:], fill_value,
+                               a.dtype)
+                return jnp.concatenate([a, pad], axis=1)
+            return (grow(tables),
+                    topk.TopK(keys=grow(heaps.keys),
+                              estimates=grow(heaps.estimates, -jnp.inf),
+                              filled=grow(heaps.filled)),
+                    grow(queue))
+
+        # built per plane, so a plane's programs are traced with the code
+        # that is there when it is made
+        self._epoch = jax.jit(_routed_epoch, static_argnames=("cols",),
+                              donate_argnames=("tables", "heaps"))
+        self._append = jax.jit(_routed_append, static_argnames=("aligned",),
+                               donate_argnames=("queue",))
+        self._query = jax.jit(_routed_query)
+        self._grow = jax.jit(_routed_grow, out_shardings=self._sharded)
+        n, k = self.shards, int(track_top or 0)
+        self.tables, self.heaps, self.queue = jax.jit(
+            lambda: (jnp.zeros((n, 0, spec.depth, spec.width),
+                               spec.storage_dtype),
+                     topk.TopK(keys=jnp.zeros((n, 0, k), jnp.uint32),
+                               estimates=jnp.zeros((n, 0, k), jnp.float32),
+                               filled=jnp.zeros((n, 0, k), bool)),
+                     jnp.zeros((n, 0, ops.ring_width(self.share)),
+                               jnp.uint32)),
+            out_shardings=self._sharded)()
+
+    @property
+    def tracker(self) -> Optional[topk.TopK]:
+        """The shards' (n, T, K) heaps, each of the keys its shard owns
+        (None without tracking)."""
+        return None if self.track_top is None else self.heaps
+
+    @property
+    def queue_capacity(self) -> int:
+        return self.shards * self.share
+
+    def add(self, name: str) -> int:
+        self.names.append(name)
+        self.tables, self.heaps, self.queue = self._grow(
+            self.tables, self.heaps, self.queue)
+        self.fill = np.concatenate(
+            [self.fill, np.zeros((self.shards, 1), np.int64)], axis=1)
+        self._g_tenants.set(len(self.names))
+        return len(self.names) - 1
+
+    def pending(self) -> int:
+        return int(self.fill.sum())
+
+    def queue_free(self, row: int) -> int:
+        return self.queue_capacity - int(self.fill[:, row].sum())
+
+    def queue_append_rows(self, rows, batches) -> None:
+        """Append tenant microbatches in ONE launch: each batch is cut in
+        contiguous pieces, one per shard, that leave the row's shard fills
+        within one of each other (the shards fill evenly)."""
+        with obs.span("queue_append", plane=self.label,
+                      rows=len(rows)) as sp:
+            if obs.recording():
+                sp.set_metadata(events=sum(int(b.size) for b in batches))
+            n = self.shards
+            fill = self.fill[:, rows]                         # (n, R)
+            total = fill.sum(axis=0) + [b.size for b in batches]
+            take = total // n + (np.arange(n)[:, None] < total % n) - fill
+            width = ops.CHUNK * -(-int(take.max()) // ops.CHUNK)
+            keys = np.zeros((n, len(rows), width), np.uint32)
+            for i, b in enumerate(batches):
+                ends = np.cumsum(take[:, i])
+                for s in range(n):
+                    keys[s, i, :take[s, i]] = b[ends[s] - take[s, i]:ends[s]]
+            meta = np.stack([np.broadcast_to(np.asarray(rows), fill.shape),
+                             fill, take], axis=1).astype(np.int32)
+            self.queue = self._append(
+                self.queue, jax.device_put(keys, self._sharded),
+                jax.device_put(meta, self._sharded),
+                aligned=not fill.any())
+            self.fill[:, rows] += take
+
+    def _settle(self, wait: bool = True) -> None:
+        """Fold the device's counts of the epochs landed so far into the
+        registry (copied to the host without blocking at dispatch).  With
+        `wait` a read here waits for an epoch still running; without it
+        (at the next flush, so the chip is never left idle while the host
+        waits) such an epoch's counts stay pending."""
+        running = []
+        for stats in self._pending_stats:
+            if not wait and not stats.is_ready():
+                running.append(stats)
+                continue
+            got = np.asarray(stats)                           # (n, 2)
+            self._m_dropped.inc(int(got[:, 1].sum()))
+            recv = got[:, 0]
+            if recv.sum():
+                self._g_skew.set(float(recv.max() / recv.mean()))
+        self._pending_stats = running
+
+    def flush(self, dense: bool = False, reason: str = "explicit") -> int:
+        """Land every tenant's pending events: ONE `_routed_epoch`
+        program over the mesh (see the class docstring).  `reason` labels
+        the epoch's `cms.flush_epoch` span, which carries the mesh's
+        `shards` and the events it routes (`routed_keys`)."""
+        if dense:
+            raise ValueError("a key-routed plane has no dense flush")
+        pending = self.pending()
+        if pending == 0:
+            return 0
+        self._settle(wait=False)
+        active = np.flatnonzero(self.fill.sum(axis=0)).astype(np.int32)
+        with obs.span("flush_epoch", cpu=True, plane=self.label,
+                      rows=int(active.size), events=pending, reason=reason,
+                      shards=self.shards, routed_keys=pending):
+            with obs.span("route", plane=self.label, shards=self.shards):
+                fill = self.fill[:, active]
+                cols = min(self.queue.shape[2],
+                           ops.CHUNK * -(-int(fill.max()) // ops.CHUNK))
+                epoch = self.rng.next()[1]
+                lanes = np.stack([self._lanes,
+                                  np.full(self.shards, epoch, np.uint32)],
+                                 axis=1)
+                fill = jax.device_put(fill.astype(np.int32), self._sharded)
+                lanes = jax.device_put(lanes, self._sharded)
+                rows = jax.device_put(active, self._replicated)
+            ops.note_dispatch("routed_epoch")
+            self.tables, self.heaps, stats = self._epoch(
+                self.tables, self.heaps, self.queue, fill, rows, lanes,
+                cols=cols)
+            stats.copy_to_host_async()
+            self._pending_stats.append(stats)
+            self.fill[:] = 0
+        self._m_routed.inc(pending)
+        self._note_flush(pending)
+        return pending
+
+    def query_rows(self, keys: jnp.ndarray) -> jnp.ndarray:
+        """(T, N) estimates, tenant-ordered (keys (N,) for every tenant, or
+        (T, N)): one routed read over the mesh."""
+        keys = np.asarray(keys)
+        t = len(self.names)
+        if keys.ndim == 1:
+            keys = np.broadcast_to(keys, (t, keys.size))
+        return self._read(np.arange(t, dtype=np.int32), keys)
+
+    def query_row(self, row: int, keys: jnp.ndarray) -> jnp.ndarray:
+        """Estimates for one tenant: one routed read over the mesh."""
+        return self._read(np.asarray([row], np.int32),
+                          np.asarray(keys)[None])[0]
+
+    def _read(self, rows: np.ndarray, probes: np.ndarray) -> jnp.ndarray:
+        self._settle()
+        ops.note_dispatch("routed_query")
+        return self._query(self.tables,
+                           jax.device_put(rows, self._replicated),
+                           jax.device_put(probes.astype(np.uint32),
+                                          self._replicated))
+
+    def topk_row(self, row: int):
+        """(keys, estimates, filled) of one tenant's heap, estimate-sorted:
+        the shards' heaps merged.  Each shard's heap holds keys it alone
+        owns, scored by the flush that last touched the row, so the merge
+        is a plain top-k of the union and its estimates are the query
+        answers."""
+        self._settle()
+        tk = self.heaps
+        keys = np.asarray(tk.keys)[:, row].ravel()
+        est = np.asarray(tk.estimates)[:, row].ravel()
+        filled = np.asarray(tk.filled)[:, row].ravel()
+        est = np.where(filled, est, -np.inf)
+        top = np.argsort(-est, kind="stable")[:self.track_top]
+        return keys[top], est[top], filled[top]
+
+    def table_row(self, row: int) -> jnp.ndarray:
+        raise ValueError("a key-routed tenant's table is split over the "
+                         "mesh's shards: read it with query")
+
+
 class CountService:
     """Registry of named sketches bucketed into fused-ingest planes."""
 
@@ -1175,11 +1449,19 @@ class CountService:
                  seed: int = 0, track_top: Optional[int] = None,
                  metrics: Optional[obs.MetricsRegistry] = None,
                  probe: Optional[obs.AccuracyProbe] = None,
-                 tier: Optional[TierSpec] = None):
+                 tier: Optional[TierSpec] = None, mesh=None):
         if queue_capacity < 1:
             raise ValueError("queue_capacity must be positive")
         if track_top is not None and track_top < 1:
             raise ValueError("track_top must be positive")
+        if mesh is not None:
+            if len(mesh.axis_names) != 1:
+                raise ValueError("a key-routed service takes a 1-D mesh: "
+                                 "its one axis shards the keys")
+            if tier is not None:
+                raise ValueError("tiered planes under a mesh are not "
+                                 "supported")
+        self.mesh = mesh
         self.default_spec = spec
         self.queue_capacity = int(queue_capacity)
         self.seed = int(seed)
@@ -1271,6 +1553,11 @@ class CountService:
         if admission is not None and self.track_top is None:
             raise ValueError("tracker-fed admission needs the heavy-hitter "
                              "plane: construct the service with track_top=K")
+        if self.mesh is not None and (window is not None
+                                      or admission is not None):
+            raise ValueError("a key-routed service (mesh=...) serves plain "
+                             "tenants: windows and admission are not "
+                             "supported under a mesh")
         if window is not None:
             if spec is not None and spec != window.sketch:
                 raise ValueError("pass the sketch spec inside WindowSpec "
@@ -1290,7 +1577,13 @@ class CountService:
                 raise ValueError("no spec: pass one (or a WindowSpec), or "
                                  "construct the service with a default")
             plane = self._planes.get(spec)
-            if plane is None:
+            if plane is None and self.mesh is not None:
+                plane = self._planes.setdefault(
+                    spec, RoutedPlane(spec, self.queue_capacity, self.mesh,
+                                      self.seed, track_top=self.track_top,
+                                      metrics=self.metrics,
+                                      label=f"p{len(self._planes)}"))
+            elif plane is None:
                 plane = self._planes.setdefault(
                     spec, TenantPlane(spec, self.queue_capacity, self.seed,
                                       track_top=self.track_top,
@@ -1734,6 +2027,9 @@ class CountService:
 
     def snapshot(self, root: str, step: int) -> str:
         """Atomic checkpoint of every plane (pending ring events included)."""
+        if self.mesh is not None:
+            raise ValueError("snapshot and restore of a key-routed service "
+                             "are not supported")
         return checkpoint.save(root, step, self._tree(),
                                metadata=self._meta())
 

@@ -124,6 +124,15 @@ def test_merge_spec_mismatch_raises():
         merge(a, b)
 
 
+def test_spec_refuses_width_past_int32_columns():
+    """`row_hashes` casts columns to int32: a row of 2^31 cells or more
+    would index the wrong cells, so the spec refuses it up front."""
+    SketchSpec(width=2 ** 31 - 128)
+    for width in (2 ** 31, 2 ** 32 + 128):
+        with pytest.raises(ValueError, match="row_hashes"):
+            SketchSpec(width=width)
+
+
 def test_sketch_is_checkpointable_pytree():
     s = update_batched(init(SketchSpec(width=128, depth=2)),
                        _zipf_keys(100, 50), jax.random.PRNGKey(0))

@@ -100,6 +100,26 @@ def test_update_score_rows_compiles(as_tpu, one_chip):
     assert engines == {("update_score_rows", "xla"): 1}
 
 
+def test_routed_update_lands_in_place_at_shard_width(one_chip):
+    """The key-routed epoch's update (`update_score_rows`' in-place
+    engine) on one shard of `web1t_routed4`: its 5.9 GB stack, donated, is
+    the program's output in place, beside temporaries of the batch's
+    size."""
+    spec = SketchSpec(width=1_468_795_776, depth=2, counter=CMLS16)
+    n = 4 * BATCH
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        _tables(spec, 1), ((1, n), jnp.uint32), ((1, n), jnp.float32), RNG,
+        ((1,), jnp.int32), ((1, 16 + n), jnp.uint32))]
+    compiled = jax.jit(
+        lambda t, k, w, r, rows, c: ops.update_score_rows(
+            t, spec, k, r, rows, c, weights=w, engine="inplace"),
+        donate_argnums=0).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    stack = spec.depth * spec.width * 2
+    assert mem.alias_size_in_bytes == stack
+    assert mem.temp_size_in_bytes < stack / 1000
+
+
 @pytest.mark.parametrize("width,engine", [(VMEM_WIDTH, "xla"),
                                           (WIDTH, "jnp")])
 def test_update_rows_compiles(as_tpu, one_chip, width, engine):
